@@ -2,18 +2,23 @@
 prefill/decode with the engine's slot-based KV cache.
 
     PYTHONPATH=src python examples/serve_batched.py
+
+Exits nonzero if any request did not finish or failed.
 """
+import sys
 import time
 
 import jax
 import numpy as np
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models import model as model_lib
 from repro.serve.engine import ServingEngine
 
 
-def main() -> None:
+def main() -> int:
+    compile_cache.enable()
     cfg = configs.get_config("llama3.2-1b", smoke=True)
     params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(cfg, params, max_batch=8, max_len=256)
@@ -25,10 +30,14 @@ def main() -> None:
                                     size=rng.integers(4, 40)),
                        max_new_tokens=16)
             for _ in range(24)]
-    for r in reqs:
-        r.done.wait(300)
+    unfinished = [r.rid for r in reqs if not r.done.wait(300)]
     wall = time.perf_counter() - t0
     eng.stop()
+    failed = [r.rid for r in reqs if r.error is not None]
+    if unfinished or failed:
+        print(f"unfinished requests {unfinished}, failed {failed}: "
+              f"{eng.error!r}", file=sys.stderr)
+        return 1
 
     lat = [r.finish_t - r.submit_t for r in reqs]
     print(f"served {len(reqs)} requests in {wall:.2f}s "
@@ -38,7 +47,8 @@ def main() -> None:
           f"tokens/step vs 1.0 unbatched)")
     print(f"latency p50={np.percentile(lat, 50)*1e3:.0f}ms "
           f"p95={np.percentile(lat, 95)*1e3:.0f}ms")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

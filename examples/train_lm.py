@@ -12,6 +12,7 @@ import argparse
 import dataclasses
 
 from repro import configs
+from repro.launch import compile_cache
 from repro.models.config import LayerSpec, uniform_groups
 from repro.train.optimizer import make_optimizer
 from repro.train.trainer import Trainer, TrainerConfig
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     base = configs.get_config("llama3.2-1b", smoke=True)
     cfg = dataclasses.replace(

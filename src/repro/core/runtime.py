@@ -49,7 +49,8 @@ from typing import Any
 from repro.core import messages as msg
 from repro.core import transport as tp
 from repro.core.graph import TaskGraph
-from repro.core.server import Driver, EpochStats, RunResult, ServerCore
+from repro.core.server import (Driver, EpochStats, RunResult, ServerCore,
+                               TaskError)
 from repro.core.store import ObjectStore
 
 __all__ = ["EpochStats", "RunResult", "ServerCore", "Driver",
@@ -91,6 +92,10 @@ class InprocDriver(Driver):
         for ev in batch:
             kind = ev[0]
             if kind == "finished":
+                fins.append((int(ev[1]), int(ev[2])))
+            elif kind == "erred":
+                # the epoch fails before the finish below could close it
+                events.append(("erred", int(ev[1]), ev[3]))
                 fins.append((int(ev[1]), int(ev[2])))
             elif kind == "worker-lost":
                 events.append(("lost", ev[1], list(ev[2])))
@@ -975,28 +980,47 @@ class ThreadRuntime(ServerCore):
                     # delay the next epoch
                     continue
                 self.running[wid] = tid
-            ev = self.events
-            if ev is not None:
-                ev.publish("task-started", tid=tid, wid=wid)
-            start = time.perf_counter_ns() if self.tracing else 0
-            if not self.zero_worker:
-                t = self.g.task(tid)
-                if t.fn is not None:
-                    # store reads unspill transparently; the put pays
-                    # the byte accounting (and any LRU spill) here
-                    args = [self.results.get(d) for d in t.inputs]
-                    self.results.put(tid, t.fn(*args) if t.args == ()
-                                     else t.fn(*t.args))
-                elif self.simulate_durations and t.duration > 0:
-                    time.sleep(t.duration)
-            with self._lock:
-                self.running.pop(wid, None)
-            if self.tracing:
-                # same clock domain as the server (thread workers):
-                # _note_timing folds + publishes, offset ends up ~0
-                self._note_timing(
-                    wid, ((tid, recv, start, time.perf_counter_ns(), 0),))
+            self._execute(wid, tid, recv)
+
+    def _execute(self, wid: int, tid: int, recv: int = 0) -> None:
+        """Run one dequeued task and report it to the server.  A task
+        that raises is reported as ``erred`` with its exception, which
+        fails the task's epoch; the worker lives on.  A task whose input
+        erred is not run and errs with that input's exception."""
+        ev = self.events
+        if ev is not None:
+            ev.publish("task-started", tid=tid, wid=wid)
+        start = time.perf_counter_ns() if self.tracing else 0
+        err = None
+        if not self.zero_worker:
+            t = self.g.task(tid)
+            if t.fn is not None:
+                # store reads unspill transparently; the put pays
+                # the byte accounting (and any LRU spill) here
+                args = [self.results.get(d) for d in t.inputs]
+                err = next((a.error for a in args
+                            if isinstance(a, TaskError)), None)
+                if err is None:
+                    try:
+                        val = (t.fn(*args) if t.args == ()
+                               else t.fn(*t.args))
+                    except Exception as exc:
+                        err = exc
+                self.results.put(tid, val if err is None
+                                 else TaskError(err))
+            elif self.simulate_durations and t.duration > 0:
+                time.sleep(t.duration)
+        with self._lock:
+            self.running.pop(wid, None)
+        if self.tracing:
+            # same clock domain as the server (thread workers):
+            # _note_timing folds + publishes, offset ends up ~0
+            self._note_timing(
+                wid, ((tid, recv, start, time.perf_counter_ns(), 0),))
+        if err is None:
             self.transport.worker_send(wid, ("finished", tid, wid))
+        else:
+            self.transport.worker_send(wid, ("erred", tid, wid, err))
 
 
 class ProcessRuntime(ServerCore):

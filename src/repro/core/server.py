@@ -67,6 +67,17 @@ from repro.core.graph import Task, TaskGraph
 from repro.core.store import ObjectStore
 
 
+class TaskError:
+    """Stored in place of the value of a task that raised: a dependent
+    that finds it among its inputs errs with ``error`` instead of
+    running."""
+
+    __slots__ = ("error",)
+
+    def __init__(self, error: BaseException):
+        self.error = error
+
+
 @dataclasses.dataclass
 class EpochStats:
     """Per-epoch accounting: one record per ``submit_tasks`` call (the
@@ -520,6 +531,15 @@ class ServerCore:
 
     def _fail_epoch(self, e: EpochStats, error: BaseException) -> None:
         self._finish_epoch(e, error=error)
+
+    def _task_erred(self, tid: int, error: BaseException) -> None:
+        """A task raised: fail the epoch that owns it with the task's own
+        exception, which ``Future.result`` re-raises.  The task's
+        ``finished`` follows in the same batch, so the reactor's
+        accounting stays whole; its dependents err in turn, unrun."""
+        i = bisect.bisect_right(self._range_los, tid) - 1
+        if i >= 0 and tid < self._range_epochs[i].hi:
+            self._fail_epoch(self._range_epochs[i], error)
 
     def _quarantine_epoch(self, e: EpochStats, tasks,
                           exc: BaseException) -> None:
@@ -1199,6 +1219,8 @@ class ServerCore:
                     self.driver.queue_discard(int(rw), int(tid))
                 if ev[2]:
                     self.results.update(ev[2])
+            elif kind == "erred":
+                self._task_erred(ev[1], ev[2])
             elif kind == "lost":
                 self._worker_lost(ev[1], ev[2])
             elif kind == "gather-reply":

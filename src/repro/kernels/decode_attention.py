@@ -7,6 +7,11 @@ assigned archs) against one KV block, carrying online-softmax state in
 VMEM scratch.  Valid lengths arrive via scalar prefetch (SMEM), masking
 both the tail beyond ``lengths`` and, for sliding-window layers, the
 prefix before ``lengths - window``.
+
+Blocks are head-major, as in :mod:`repro.kernels.flash_attention`: the
+wrapper views the cache as ``(B,KV,T,hd)`` and the query as
+``(B,KV,G,hd)``, so each block's last two dimensions are a
+(time, head-dim) or (group, head-dim) tile the TPU compiler accepts.
 """
 from __future__ import annotations
 
@@ -39,9 +44,9 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(live)
     def _run():
-        q = q_ref[0, 0, 0, :, :].astype(jnp.float32)  # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)    # (blk_k, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)          # (G, hd)
+        k = k_ref[0, 0].astype(jnp.float32)          # (blk_k, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap:
@@ -65,7 +70,7 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(ki == kv_blocks - 1)
     def _emit():
-        o_ref[0, 0, 0, :, :] = (acc_scr[...]
+        o_ref[0, 0] = (acc_scr[...]
                                 / jnp.maximum(l_scr[...], 1e-30)[:, None]
                                 ).astype(o_ref.dtype)
 
@@ -82,7 +87,7 @@ def decode_attention(q, k, v, *, lengths, window=None, softcap=None,
     blk_k = min(blk_k, t)
     assert t % blk_k == 0
     nk = t // blk_k
-    qg = q.reshape(b, 1, kv, g, hd)
+    qg = q.reshape(b, kv, g, hd)
 
     kernel = functools.partial(_dec_kernel, scale=scale, window=window,
                                softcap=softcap, blk_k=blk_k, kv_blocks=nk)
@@ -90,15 +95,15 @@ def decode_attention(q, k, v, *, lengths, window=None, softcap=None,
         num_scalar_prefetch=1,
         grid=(b, kv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, g, hd),
-                         lambda bi, ci, ki, lens: (bi, 0, ci, 0, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd),
-                         lambda bi, ci, ki, lens: (bi, ki, ci, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd),
-                         lambda bi, ci, ki, lens: (bi, ki, ci, 0)),
+            pl.BlockSpec((1, 1, g, hd),
+                         lambda bi, ci, ki, lens: (bi, ci, 0, 0)),
+            pl.BlockSpec((1, 1, blk_k, hd),
+                         lambda bi, ci, ki, lens: (bi, ci, ki, 0)),
+            pl.BlockSpec((1, 1, blk_k, hd),
+                         lambda bi, ci, ki, lens: (bi, ci, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, g, hd),
-                               lambda bi, ci, ki, lens: (bi, 0, ci, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, hd),
+                               lambda bi, ci, ki, lens: (bi, ci, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g,), jnp.float32),
             pltpu.VMEM((g,), jnp.float32),
@@ -107,7 +112,8 @@ def decode_attention(q, k, v, *, lengths, window=None, softcap=None,
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, kv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(lengths, jnp.int32), qg, k, v)
+    )(jnp.asarray(lengths, jnp.int32), qg, k.swapaxes(1, 2),
+      v.swapaxes(1, 2))
     return out.reshape(b, 1, h, hd)

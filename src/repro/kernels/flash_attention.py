@@ -8,8 +8,13 @@ skipped with ``pl.when`` so the work matches a real flash kernel.  GQA is
 expressed in the K/V index maps (kv head = q head // group), so no
 expanded K/V ever materialises.
 
-TARGET: TPU (MXU-aligned 128x128 tiles); VALIDATED here with
-``interpret=True`` against kernels/ref.py.
+Blocks are head-major: the wrapper moves heads ahead of the sequence
+(``(B,H,S,hd)``) so every block is ``(1, 1, blk, hd)`` and its last two
+dimensions are a (sequence, head-dim) tile, which is what the TPU
+compiler's tiling rules accept.  A ``(1, blk, 1, hd)`` block over the
+model's ``(B,S,H,hd)`` layout puts a size-1 head block second-minor and
+is refused.  Checked against kernels/ref.py in interpret mode
+(tests/test_kernels.py) and compiled for v5e (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -41,9 +46,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     k_start = ki * blk_k
 
     def _block():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)      # (blk_q, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (blk_k, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)            # (blk_q, hd)
+        k = k_ref[0, 0].astype(jnp.float32)            # (blk_k, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap:
@@ -87,7 +92,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == kv_blocks - 1)
     def _emit():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -115,21 +120,21 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, blk_q, 1, hd),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd),
-                         lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, blk_k, 1, hd),
-                         lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
+            pl.BlockSpec((1, 1, blk_q, hd),
+                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, blk_k, hd),
+                         lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
+            pl.BlockSpec((1, 1, blk_k, hd),
+                         lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, blk_q, 1, hd),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, blk_q, hd),
+                               lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q,), jnp.float32),      # running max m
             pltpu.VMEM((blk_q,), jnp.float32),      # running sum l
             pltpu.VMEM((blk_q, hd), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-    )(q, k, v)
-    return out
+    )(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2))
+    return out.swapaxes(1, 2)

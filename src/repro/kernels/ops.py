@@ -1,8 +1,11 @@
-"""Jit-friendly op dispatch: Pallas TPU kernels when targeting TPU, pure-jnp
-reference otherwise.  The model code only ever imports this module.
+"""Jit-friendly op dispatch between the pure-jnp references and the Pallas
+TPU kernels.  The model code only ever imports this module.
 
-``set_impl('pallas')`` switches hot ops to the Pallas implementations (used
-by kernel tests under ``interpret=True`` on CPU, and the real path on TPU).
+The default is ``ref`` on every backend, the TPU included: nothing in the
+program switches it.  ``set_impl('pallas')`` routes the hot ops to the
+Pallas kernels (``interpret=True`` runs them on the CPU, as the tests do;
+``chip_smoke.py`` runs them compiled on the chip).  Calls already traced
+under ``jax.jit`` keep the implementation they were traced with.
 """
 from __future__ import annotations
 

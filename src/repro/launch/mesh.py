@@ -9,23 +9,17 @@ gradient all-reduce across the (slower) inter-pod links.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist
-    # on newer jax; Auto is the default there, so omitting is equivalent
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")) -> jax.sharding.Mesh:
     """Tiny mesh over however many (CPU) devices exist — for smoke tests."""
     n = len(jax.devices())
     d = min(n, shape[0] * shape[1])
-    return jax.make_mesh((d, 1), axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh((d, 1), axes, axis_types=(AxisType.Auto,) * len(axes))

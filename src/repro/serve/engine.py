@@ -50,6 +50,7 @@ class Request:
     submit_t: float = 0.0
     finish_t: float = 0.0
     tenant: str = "default"       # event-stream key (multi-tenant views)
+    error: BaseException | None = None   # set (with done) if serving failed
 
 
 def _bucket(n: int, buckets=(16, 32, 64, 128, 256, 512, 1024)) -> int:
@@ -85,6 +86,7 @@ class ServingEngine:
         self.n_generated = 0
         self._stop = threading.Event()
         self._rid = 0
+        self.error: BaseException | None = None   # why the loop stopped
 
         def prefill_fn(params, tokens, cache):
             return model_lib.prefill(params, cfg, tokens, cache)
@@ -143,7 +145,21 @@ class ServingEngine:
         if ev is not None:
             ev.publish("request-enter", rid=req.rid, tenant=tenant)
         self.inbox.put(req)
+        if self.error is not None:
+            self._fail_waiting(self.error)
         return req
+
+    def _fail(self, req: Request, error: BaseException) -> None:
+        req.error = error
+        req.finish_t = time.perf_counter()
+        req.done.set()
+
+    def _fail_waiting(self, error: BaseException) -> None:
+        while True:
+            try:
+                self._fail(self.inbox.get_nowait(), error)
+            except queue.Empty:
+                return
 
     # ------------------------------------------------------------------
     def _admit(self) -> None:
@@ -154,6 +170,9 @@ class ServingEngine:
                 req = self.inbox.get_nowait()
             except queue.Empty:
                 return
+            # the slot is taken before the prefill, so a failing prefill
+            # fails this request with the rest
+            self.active[slot] = req
             # prefill prompt[:-1]; the last prompt token goes through the
             # normal decode path, yielding the first generated token with a
             # correctly positioned cache write.
@@ -175,13 +194,25 @@ class ServingEngine:
                     if hasattr(g, "at") else g, self.cache, one_cache)
             self.pos[slot] = s - 1
             self._next_in[slot] = int(req.prompt[-1])
-            self.active[slot] = req
             ev = self._cluster.events
             if ev is not None:
                 ev.publish("request-admit", rid=req.rid,
                            tenant=req.tenant, slot=slot)
 
     def _loop(self) -> None:
+        try:
+            self._serve()
+        except Exception as exc:
+            # a failed prefill or decode must not leave callers waiting:
+            # every active and queued request fails with the error
+            self.error = exc
+            for i, req in enumerate(self.active):
+                if req is not None:
+                    self._fail(req, exc)
+                    self.active[i] = None
+            self._fail_waiting(exc)
+
+    def _serve(self) -> None:
         while not self._stop.is_set():
             self._admit()
             live = [i for i, r in enumerate(self.active) if r is not None]

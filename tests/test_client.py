@@ -242,6 +242,48 @@ def test_epoch_depending_on_released_key_fails_cleanly():
             sum(i * i for i in range(10))
 
 
+def _boom(*_):
+    raise ValueError("boom")
+
+
+@pytest.mark.parametrize("server", SERVERS)
+def test_raising_task_fails_its_future_and_pool_lives(server):
+    """A task that raises on a thread worker fails its epoch with that
+    exception at once (not a timeout), and the worker runs the next
+    task."""
+    with Cluster(server=server, runtime="thread", n_workers=1,
+                 timeout=60.0) as c:
+        f = c.client.submit(_boom)
+        t0 = time.perf_counter()
+        with pytest.raises(ValueError, match="boom"):
+            f.result(30.0)
+        assert time.perf_counter() - t0 < 1.0
+        assert c.client.submit(_sq, 6).result(30.0) == 36
+
+
+def test_dependents_of_a_raising_task_err_unrun():
+    ran = []
+
+    def after(x):
+        ran.append(x)
+        return x
+
+    with Cluster(server="rsds", runtime="thread", n_workers=2,
+                 timeout=60.0) as c:
+        tasks = [Task(0, (), fn=_boom), Task(1, (0,), fn=after),
+                 Task(2, (), fn=_leaf, args=(7,))]
+        futs = c.client.submit_graph(TaskGraph(tasks, name="erred"))
+        with pytest.raises(ValueError, match="boom"):
+            futs.result(30.0)
+        # a later epoch that depends on the erred key errs the same way
+        g = c.client.submit(after, futs[0])
+        with pytest.raises(ValueError, match="boom"):
+            g.result(30.0)
+        assert ran == []
+        assert c.client.submit_graph(_fn_graph()).result(30.0)[10] == \
+            sum(i * i for i in range(10))
+
+
 def test_submit_on_closed_cluster_raises():
     c = Cluster(server="rsds", runtime="thread", n_workers=2)
     c.close()

@@ -88,9 +88,9 @@ def test_sharded_train_step_runs_on_host_mesh():
         from repro.train.train_step import make_train_step
 
         cfg = configs.get_config("llama3.2-1b", smoke=True)
-        from repro.launch.mesh import _axis_type_kwargs
+        from jax.sharding import AxisType
         mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             **_axis_type_kwargs(2))
+                             axis_types=(AxisType.Auto,) * 2)
         params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
         pspecs = sharding.param_shardings(cfg, mesh)
         params = jax.device_put(params, pspecs)

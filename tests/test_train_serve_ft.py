@@ -162,6 +162,25 @@ def test_straggler_mitigation_moves_work():
     assert elapsed < 0.4, f"stealing failed to rebalance ({elapsed:.2f}s)"
 
 
+@pytest.mark.parametrize("slow", [None, {0: 0.01, 1: 0.01}],
+                         ids=["plain", "straggler"])
+def test_microbatch_error_fails_the_step(slow):
+    """A microbatch that raises fails its step with that exception, on
+    the runtime's worker loop and on the straggler copy of it alike."""
+    mc = MicrobatchCoordinator(CFG, n_executors=2, n_microbatches=4,
+                               slow_workers=slow)
+
+    def refused(*_):
+        raise FloatingPointError("grad refused")
+
+    mc._grad = refused
+    t0 = time.perf_counter()
+    with pytest.raises(FloatingPointError, match="grad refused"):
+        mc.train_step(SyntheticDataset(CFG, 8, 32).batch_at(0))
+    assert time.perf_counter() - t0 < 10.0
+    mc.close()
+
+
 # ---------------------------------------------------------------------------
 # serving engine
 # ---------------------------------------------------------------------------
@@ -199,6 +218,53 @@ def test_serving_engine_matches_reference(rng):
     for p, r in zip(prompts, reqs):
         want = _reference_generate(cfg, params, p, 6)
         assert r.out_tokens == want, (r.out_tokens, want)
+
+
+@pytest.mark.parametrize("step", ["_prefill", "_decode"])
+def test_serving_engine_fails_requests_when_a_step_raises(step):
+    """A prefill or decode that raises fails every active and queued
+    request with that error instead of leaving callers to wait out a
+    timeout."""
+    from repro.serve.engine import ServingEngine
+    params = model_lib.init_params(jax.random.PRNGKey(1), CFG)
+    eng = ServingEngine(CFG, params, max_batch=2, max_len=64)
+
+    def refused(*_):
+        raise RuntimeError(f"{step} refused")
+
+    setattr(eng, step, refused)
+    eng.start()
+    # three requests for two slots: one is still queued when decode fails
+    reqs = [eng.submit(np.arange(1, n), max_new_tokens=4)
+            for n in (5, 9, 12)]
+    for r in reqs:
+        assert r.done.wait(30)
+    late = eng.submit(np.arange(1, 6), max_new_tokens=4)
+    assert late.done.wait(5)
+    eng.stop()
+    assert isinstance(eng.error, RuntimeError)
+    assert all(r.error is eng.error and not r.out_tokens
+               for r in reqs + [late])
+
+
+def test_serve_example_exits_nonzero_on_failed_requests(monkeypatch):
+    import importlib.util
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    from repro.serve.engine import ServingEngine
+    path = Path(__file__).resolve().parent.parent / "examples" / \
+        "serve_batched.py"
+    spec = importlib.util.spec_from_file_location("serve_batched", path)
+    example = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(example)
+
+    def refused(self, fn, *args):
+        raise RuntimeError("compile refused")
+
+    monkeypatch.setattr(compile_cache, "enable", lambda: "")
+    monkeypatch.setattr(ServingEngine, "_call", refused)
+    assert example.main() == 1
 
 
 def test_elastic_scale_up_and_down():
