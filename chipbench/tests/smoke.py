@@ -1,0 +1,49 @@
+"""Cells of the benchmark cut to a size the CPU runs in seconds.
+
+The widths shrink and nothing else: the block pattern, the traffic mix and
+the harness are the cells' own.  Used by the tests and by ``calibrate.py``
+when it rehearses on the CPU.
+"""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+BENCH_DIR = HERE.parent
+ROOT = BENCH_DIR.parent
+for p in (str(ROOT / "src"), str(BENCH_DIR)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import spec  # noqa: E402
+
+SMOKE_WIDTHS = {
+    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 512,
+    "mamba_d_state": 16, "mamba_headdim": 16, "chunk_size": 32,
+}
+
+PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
+         "hbm_bytes": 1e9}
+
+
+#: the cells of BENCHMARK.json
+CELLS = {w["name"]: (w["config"], w["traffic"]) for w in
+         spec.load_json(ROOT / "BENCHMARK.json")["workloads"]}
+
+
+def smoke_cell(name: str, dtype: str = "float32", max_batch: int = 4,
+               limit: float = 0.05) -> spec.Cell:
+    cell = spec.load_cell(name, *CELLS[name])
+    conf = dict(cell.config)
+    conf.update({k: v for k, v in SMOKE_WIDTHS.items() if k in conf})
+    conf["torch_dtype"] = dtype
+    # two layers of a one-layer pattern, one period of a longer one
+    conf["num_hidden_layers"] = len(conf["block_pattern"]) * (
+        2 if len(conf["block_pattern"]) == 1 else 1)
+    sizes = dict(cell.sizes, max_batch=max_batch,
+                 check={"requests": 4, "batch": 2},
+                 limits={"max_logit_gap": limit})
+    return spec.Cell(name=name, chips=1, config=conf, traffic=cell.traffic,
+                     sizes=sizes)
