@@ -2,8 +2,9 @@
 
 One *layer* = (pre-norm -> mixer block -> residual) + optional
 (pre-norm -> MLP/MoE -> residual), with gemma2-style post-norms when
-``spec.post_norms``.  A *group* scans a repeating pattern of layers with
-stacked parameters; weight-shared slots (zamba2's shared attention) are
+``spec.post_norms``; the two halves run under ``jax.named_scope`` names,
+the mixer's kind (``attn``, ``mamba2``, ...) and ``mlp`` or ``moe``.  A
+*group* scans a repeating pattern of layers with stacked parameters; weight-shared slots (zamba2's shared attention) are
 closed over instead of scanned.  ``cfg.unroll`` switches the scan to a
 Python loop — used by the dry-run cost-accounting variants (DESIGN.md).
 """
@@ -79,45 +80,47 @@ def apply_layer(params: Params, cfg: ModelConfig, spec: LayerSpec,
                 ) -> tuple[jax.Array, Params | None, dict]:
     aux = dict(ZERO_AUX)
     if spec.kind != "none":
-        h = rmsnorm(params["pre_norm"], x, eps=cfg.norm_eps)
-        if spec.kind == "attn":
-            h, new_cache = attention.apply_attn(
-                params["mixer"], cfg, spec, h, ctx["positions"], cache)
-        elif spec.kind == "mla":
-            h, new_cache = attention.apply_mla(
-                params["mixer"], cfg, spec, h, ctx["positions"], cache,
-                absorbed=ctx.get("mla_absorbed", False))
-        elif spec.kind == "cross_attn":
-            h, new_cache = attention.apply_cross_attn(
-                params["mixer"], cfg, spec, h, ctx.get("image_embeds"), cache)
-        elif spec.kind == "mamba2":
-            h, new_cache = mamba2.apply_mamba2(params["mixer"], cfg, spec, h,
-                                               cache)
-        elif spec.kind == "mlstm":
-            h, new_cache = xlstm.apply_mlstm(params["mixer"], cfg, spec, h,
-                                             cache)
-        elif spec.kind == "slstm":
-            h, new_cache = xlstm.apply_slstm(params["mixer"], cfg, spec, h,
-                                             cache)
-        else:  # pragma: no cover
-            raise ValueError(spec.kind)
-        if spec.post_norms:
-            h = rmsnorm(params["post_norm"], h, eps=cfg.norm_eps)
-        x = x + h
+        with jax.named_scope(spec.kind):
+            h = rmsnorm(params["pre_norm"], x, eps=cfg.norm_eps)
+            if spec.kind == "attn":
+                h, new_cache = attention.apply_attn(
+                    params["mixer"], cfg, spec, h, ctx["positions"], cache)
+            elif spec.kind == "mla":
+                h, new_cache = attention.apply_mla(
+                    params["mixer"], cfg, spec, h, ctx["positions"], cache,
+                    absorbed=ctx.get("mla_absorbed", False))
+            elif spec.kind == "cross_attn":
+                h, new_cache = attention.apply_cross_attn(
+                    params["mixer"], cfg, spec, h, ctx.get("image_embeds"),
+                    cache)
+            elif spec.kind == "mamba2":
+                h, new_cache = mamba2.apply_mamba2(params["mixer"], cfg,
+                                                   spec, h, cache)
+            elif spec.kind == "mlstm":
+                h, new_cache = xlstm.apply_mlstm(params["mixer"], cfg, spec,
+                                                 h, cache)
+            elif spec.kind == "slstm":
+                h, new_cache = xlstm.apply_slstm(params["mixer"], cfg, spec,
+                                                 h, cache)
+            else:  # pragma: no cover
+                raise ValueError(spec.kind)
+            if spec.post_norms:
+                h = rmsnorm(params["post_norm"], h, eps=cfg.norm_eps)
+            x = x + h
     else:
         new_cache = cache
 
     if spec.mlp != "none":
-        h = rmsnorm(params["pre_mlp_norm"], x, eps=cfg.norm_eps)
-        if spec.mlp == "moe":
-            h, moe_aux = apply_moe(params["mlp"], cfg, h)
-            aux["moe_aux_loss"] = moe_aux["moe_aux_loss"].astype(jnp.float32)
-            aux["moe_dropped"] = moe_aux["moe_dropped"].astype(jnp.float32)
-        else:
-            h = apply_mlp(params["mlp"], cfg, h)
-        if spec.post_norms:
-            h = rmsnorm(params["post_mlp_norm"], h, eps=cfg.norm_eps)
-        x = x + h
+        with jax.named_scope("moe" if spec.mlp == "moe" else "mlp"):
+            h = rmsnorm(params["pre_mlp_norm"], x, eps=cfg.norm_eps)
+            if spec.mlp == "moe":
+                h, moe_aux = apply_moe(params["mlp"], cfg, h)
+                aux = {k: moe_aux[k].astype(jnp.float32) for k in aux}
+            else:
+                h = apply_mlp(params["mlp"], cfg, h)
+            if spec.post_norms:
+                h = rmsnorm(params["post_mlp_norm"], h, eps=cfg.norm_eps)
+            x = x + h
     return x, new_cache, aux
 
 

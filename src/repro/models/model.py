@@ -8,6 +8,11 @@ Exposes the three execution paths the shape cells exercise:
 MusicGen-style multi-codebook streams (tokens (B,S,K)) and VLM image-embed
 stubs (``image_embeds`` forwarded to cross-attention layers) are handled
 here so every assigned arch shares one code path.
+
+The embedding, the layer stack, the final norm and the head run under
+``jax.named_scope`` names (``embed``, ``layers``, ``final_norm``,
+``head``), as each layer's parts do (``blocks.apply_layer``), so a device
+trace can be read by model part.
 """
 from __future__ import annotations
 
@@ -71,48 +76,54 @@ def abstract_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None):
 
 
 def _embed(params: Params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
-    if cfg.num_codebooks:
-        # tokens: (B, S, K) -> sum of per-codebook embeddings
-        embs = jax.vmap(lambda e, t: jnp.take(e, t, axis=0))(
-            params["embed"], jnp.moveaxis(tokens, -1, 0))     # (K,B,S,D)
-        x = jnp.sum(embs, axis=0)
-    else:
-        x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.scale_embed:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    return hint(x, "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        if cfg.num_codebooks:
+            # tokens: (B, S, K) -> sum of per-codebook embeddings
+            embs = jax.vmap(lambda e, t: jnp.take(e, t, axis=0))(
+                params["embed"], jnp.moveaxis(tokens, -1, 0))  # (K,B,S,D)
+            x = jnp.sum(embs, axis=0)
+        else:
+            x = jnp.take(params["embed"], tokens, axis=0)
+        if cfg.scale_embed:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        return hint(x, "batch", "seq", "embed")
 
 
 def _head(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    if cfg.num_codebooks:
-        w = params.get("head", params["embed"])  # (K,V,D) if tied
-        if "head" in params:
-            logits = jnp.einsum("bsd,kdv->bskv", x, w)
+    with jax.named_scope("head"):
+        if cfg.num_codebooks:
+            w = params.get("head", params["embed"])  # (K,V,D) if tied
+            if "head" in params:
+                logits = jnp.einsum("bsd,kdv->bskv", x, w)
+            else:
+                logits = jnp.einsum("bsd,kvd->bskv", x, w)
         else:
-            logits = jnp.einsum("bsd,kvd->bskv", x, w)
-    else:
-        if "head" in params:
-            logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
-        else:
-            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    axes = (("batch", "seq", None, "vocab") if cfg.num_codebooks
-            else ("batch", "seq", "vocab"))
-    logits = hint(logits, *axes)
-    return soft_cap(logits, cfg.final_softcap or None)
+            if "head" in params:
+                logits = jnp.einsum("bsd,dv->bsv", x, params["head"])
+            else:
+                logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        axes = (("batch", "seq", None, "vocab") if cfg.num_codebooks
+                else ("batch", "seq", "vocab"))
+        logits = hint(logits, *axes)
+        return soft_cap(logits, cfg.final_softcap or None)
 
 
 def _run(params: Params, cfg: ModelConfig, x: jax.Array, ctx: dict,
          caches: list | None):
     aux = dict(blocks.ZERO_AUX)
     new_caches = [] if caches is not None else None
-    for gi, gspec in enumerate(cfg.groups):
-        c = None if caches is None else caches[gi]
-        x, nc, ga = blocks.apply_group(params["groups"][gi], cfg, gspec, x,
-                                       ctx, c)
-        if new_caches is not None:
-            new_caches.append(nc)
-        aux = {k: aux[k] + ga[k] for k in aux}
-    x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
+    # the layer loop's own work (slicing each layer's stacked weights and
+    # caches, writing the caches back) falls under ``layers`` alone
+    with jax.named_scope("layers"):
+        for gi, gspec in enumerate(cfg.groups):
+            c = None if caches is None else caches[gi]
+            x, nc, ga = blocks.apply_group(params["groups"][gi], cfg, gspec,
+                                           x, ctx, c)
+            if new_caches is not None:
+                new_caches.append(nc)
+            aux = {k: aux[k] + ga[k] for k in aux}
+    with jax.named_scope("final_norm"):
+        x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
     return x, new_caches, aux
 
 

@@ -20,9 +20,17 @@ a caller-supplied ``tenant`` — into the same structured feed the
 runtime's control-plane events ride (:mod:`repro.core.events`), so a
 serving deployment's request streams are visible per tenant next to the
 task stream serving them.
+
+With ``tracing=True`` the engine's ``Cluster`` traces every prefill and
+decode task (:mod:`repro.core.tracing`, :meth:`ServingEngine.trace_analysis`)
+and the loop marks its phases with ``serve.*`` profiler spans, so a
+``jax.profiler`` trace shows what the host was doing while the device
+waited (``docs/tracing.md``, "Serving spans").  Every request carries
+``perf_counter`` stamps of its admission and of each token it is served.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -49,6 +57,8 @@ class Request:
         default_factory=threading.Event)
     submit_t: float = 0.0
     finish_t: float = 0.0
+    admit_t: float = 0.0          # taken into a slot
+    token_t: list = dataclasses.field(default_factory=list)  # per token
     tenant: str = "default"       # event-stream key (multi-tenant views)
     error: BaseException | None = None   # set (with done) if serving failed
 
@@ -66,12 +76,14 @@ def _bucket(n: int, buckets=(16, 32, 64, 128, 256, 512, 1024)) -> int:
 #: ever spilling in practice.
 DEFAULT_MEMORY_LIMIT = 256 * 2**20
 
+_NO_SPAN = contextlib.nullcontext()
+
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params: Any, *, max_batch: int = 8,
                  max_len: int = 256,
                  memory_limit: int | None = DEFAULT_MEMORY_LIMIT,
-                 events=None):
+                 events=None, tracing: bool = False):
         assert not cfg.vision_dim, "engine example supports pure-LM archs"
         self.cfg = cfg
         self.params = params
@@ -87,6 +99,7 @@ class ServingEngine:
         self._stop = threading.Event()
         self._rid = 0
         self.error: BaseException | None = None   # why the loop stopped
+        self.tracing = tracing
 
         def prefill_fn(params, tokens, cache):
             return model_lib.prefill(params, cfg, tokens, cache)
@@ -102,11 +115,15 @@ class ServingEngine:
         # submission, reused across steps and requests.  memory_limit
         # bounds its store like every other Cluster (ROADMAP PR-5
         # follow-up); events= threads the request stream into the same
-        # observability feed the runtime's control plane publishes to
+        # observability feed the runtime's control plane publishes to;
+        # tracing= needs a bus for its task spans: the caller's or a fresh
+        # one
+        trace_kw = ({"events": events or True, "tracing": True} if tracing
+                    else {"events": events})
         self._cluster = Cluster(server="rsds", scheduler="ws",
                                 n_workers=1, runtime="thread",
                                 name="serving", memory_limit=memory_limit,
-                                events=events)
+                                **trace_kw)
         self._thread = threading.Thread(target=self._loop, daemon=True)
 
     @property
@@ -118,6 +135,18 @@ class ServingEngine:
         """Live snapshot of the pool serving this engine (see
         :meth:`repro.core.server.ServerCore.observe`)."""
         return self._cluster.observe()
+
+    def trace_analysis(self):
+        """The per-task spans of every prefill and decode task so far (a
+        :class:`repro.core.tracing.TraceAnalysis`; needs ``tracing=True``)."""
+        return self._cluster.trace_analysis()
+
+    def _span(self, name: str, **args):
+        """A profiler span named ``name`` around one phase of the loop,
+        with ``args`` as its arguments; nothing when tracing is off."""
+        if not self.tracing:
+            return _NO_SPAN
+        return jax.profiler.TraceAnnotation(name, **args)
 
     def _call(self, fn, *args):
         """Run one compute on the warm pool and free its key."""
@@ -173,31 +202,40 @@ class ServingEngine:
             # the slot is taken before the prefill, so a failing prefill
             # fails this request with the rest
             self.active[slot] = req
+            req.admit_t = time.perf_counter()
             # prefill prompt[:-1]; the last prompt token goes through the
             # normal decode path, yielding the first generated token with a
             # correctly positioned cache write.
             s = len(req.prompt)
-            if s > 1:
-                recurrent = (self.cfg.mamba is not None
-                             or self.cfg.xlstm is not None)
-                # recurrent state must not see padding; attention caches
-                # mask by length so bucketed padding is safe
-                bucket = (s - 1 if recurrent
-                          else min(_bucket(s - 1), self.max_len))
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :s - 1] = req.prompt[:-1]  # right-pad
-                one_cache = model_lib.init_cache(self.cfg, 1, self.max_len)
-                _, one_cache = self._call(self._prefill, self.params,
-                                          jnp.asarray(toks), one_cache)
-                self.cache = jax.tree.map(
-                    lambda g, p: g.at[:, slot].set(p[:, 0])
-                    if hasattr(g, "at") else g, self.cache, one_cache)
-            self.pos[slot] = s - 1
-            self._next_in[slot] = int(req.prompt[-1])
-            ev = self._cluster.events
-            if ev is not None:
-                ev.publish("request-admit", rid=req.rid,
-                           tenant=req.tenant, slot=slot)
+            recurrent = (self.cfg.mamba is not None
+                         or self.cfg.xlstm is not None)
+            # recurrent state must not see padding; attention caches
+            # mask by length so bucketed padding is safe
+            bucket = (0 if s <= 1 else s - 1 if recurrent
+                      else min(_bucket(s - 1), self.max_len))
+            with self._span("serve.admit", rid=req.rid, slot=slot,
+                            bucket=bucket):
+                if s > 1:
+                    toks = np.zeros((1, bucket), np.int32)
+                    toks[0, :s - 1] = req.prompt[:-1]  # right-pad
+                    with self._span("serve.init_cache"):
+                        one_cache = model_lib.init_cache(self.cfg, 1,
+                                                         self.max_len)
+                    with self._span("serve.prefill"):
+                        _, one_cache = self._call(self._prefill, self.params,
+                                                  jnp.asarray(toks),
+                                                  one_cache)
+                    with self._span("serve.slot_copy"):
+                        self.cache = jax.tree.map(
+                            lambda g, p: g.at[:, slot].set(p[:, 0])
+                            if hasattr(g, "at") else g, self.cache,
+                            one_cache)
+                self.pos[slot] = s - 1
+                self._next_in[slot] = int(req.prompt[-1])
+                ev = self._cluster.events
+                if ev is not None:
+                    ev.publish("request-admit", rid=req.rid,
+                               tenant=req.tenant, slot=slot)
 
     def _loop(self) -> None:
         try:
@@ -213,24 +251,40 @@ class ServingEngine:
             self._fail_waiting(exc)
 
     def _serve(self) -> None:
+        step = 0
         while not self._stop.is_set():
-            self._admit()
-            live = [i for i, r in enumerate(self.active) if r is not None]
-            if not live:
+            with (jax.profiler.StepTraceAnnotation("serve.step",
+                                                   step_num=step)
+                  if self.tracing else _NO_SPAN):
+                self._iterate()
+            step += 1
+
+    def _iterate(self) -> None:
+        """One pass of the loop: admit what the free slots take, then one
+        batched decode step over the live slots (a short sleep if none)."""
+        self._admit()
+        live = [i for i, r in enumerate(self.active) if r is not None]
+        if not live:
+            with self._span("serve.idle"):
                 time.sleep(0.002)
-                continue
-            tokens = np.zeros((self.max_batch, 1), np.int32)
-            for i in live:
-                tokens[i, 0] = self._next_in[i]
+            return
+        tokens = np.zeros((self.max_batch, 1), np.int32)
+        for i in live:
+            tokens[i, 0] = self._next_in[i]
+        with self._span("serve.decode"):
             nxt, self.cache = self._call(
                 self._decode, self.params, jnp.asarray(tokens), self.cache,
                 jnp.asarray(self.pos))
+        with self._span("serve.sync"):
             nxt = np.asarray(nxt)
-            self.n_decode_steps += 1
+        t_token = time.perf_counter()
+        self.n_decode_steps += 1
+        with self._span("serve.emit"):
             for i in live:
                 req = self.active[i]
                 self.pos[i] += 1
                 req.out_tokens.append(int(nxt[i]))
+                req.token_t.append(t_token)
                 self._next_in[i] = int(nxt[i])
                 self.n_generated += 1
                 done = (len(req.out_tokens) >= req.max_new_tokens

@@ -179,6 +179,29 @@ def test_events_off_with_tracing_publishes_nothing(runtime, kw):
     assert r.stats["n_timing"] == 61     # records folded, not published
 
 
+def test_serving_engine_with_tracing_off_publishes_nothing():
+    """ServingEngine's default: its Cluster has no bus and takes no timing
+    records, and the engine marks no span."""
+    import jax
+    import numpy as np
+
+    from repro import configs
+    from repro.models import model as model_lib
+    from repro.serve.engine import ServingEngine
+    cfg = configs.get_config("llama3.2-1b", smoke=True)
+    params = model_lib.init_params(jax.random.PRNGKey(1), cfg)
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=64)
+    eng.start()
+    req = eng.submit(np.arange(1, 9), max_new_tokens=3)
+    assert req.done.wait(120) and req.error is None
+    eng.stop()
+    assert eng.events is None and not eng.tracing
+    assert eng.observe()["n_events"] == 0
+    assert eng._cluster.runtime.n_timing == 0
+    with pytest.raises(RuntimeError):
+        eng.trace_analysis()
+
+
 def test_tracing_off_publishes_no_timing(tmp_path):
     """events= without tracing=: the recorded stream carries no
     task-timing events and no timing counters move — the tracing
