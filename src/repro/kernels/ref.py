@@ -26,8 +26,10 @@ def _mask(sq: int, st: int, *, causal: bool, window: int | None,
 
 
 def _expand_kv(k: jax.Array, h: int) -> jax.Array:
-    """(B,T,KV,hd) -> (B,T,H,hd).  Broadcast-expand keeps the head dim a
-    real tensor dim so GSPMD can shard it even when KV < TP degree."""
+    """(B,T,KV,hd) -> (B,T,H,hd) for the prefill path (``_attend_dense``),
+    whose cost is its score matrix, not this copy.  Broadcast-expand keeps
+    the head dim a real tensor dim so GSPMD can shard it even when
+    KV < TP degree.  Decode does not call it."""
     kv = k.shape[2]
     if kv == h:
         return k
@@ -90,12 +92,17 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      lengths: jax.Array, window: int | None = None,
                      softcap: float | None = None,
                      scale: float = 1.0) -> jax.Array:
-    """Single-token decode. q: (B,1,H,hd); k,v: (B,T,KV,hd); lengths: (B,)."""
+    """Single-token decode. q: (B,1,H,hd); k,v: (B,T,KV,hd); lengths: (B,).
+
+    Grouped-query: q is viewed as (B, KV, G, hd) with G = H // KV, and each
+    group of G query heads attends its own KV head in place (head h reads
+    KV head h // G, the mapping ``_expand_kv`` gives).  The cache is read
+    as it stands; no copy of it with H heads is made.
+    """
     b, _, h, hd = q.shape
-    t = k.shape[1]
-    k = _expand_kv(k, h)
-    v = _expand_kv(v, h)
-    scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
+    t, kv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, kv, h // kv, hd)
+    scores = jnp.einsum("bkgd,btkd->bkgt", qg, k).astype(jnp.float32) * scale
     if softcap:
         scores = softcap * jnp.tanh(scores / softcap)
     ti = jnp.arange(t)[None, :]
@@ -104,8 +111,8 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         valid &= ti >= (lengths[:, None] - window)
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bhst,bthd->bshd", p, v)
-    return out
+    out = jnp.einsum("bkgt,btkd->bkgd", p, v)
+    return out.reshape(b, 1, h, hd)
 
 
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,

@@ -1,4 +1,6 @@
 """Pallas kernel sweeps vs pure-jnp oracles (interpret=True on CPU)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -60,6 +62,70 @@ def test_decode_attention(rng, dtype, b, t, h, kv, hd, window, cap):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         **TOL[dtype])
+
+
+def _repeat_then_attend(q, k, v, lengths, window, softcap, scale):
+    """Decode attention as plain MHA over K/V repeated to every query head."""
+    g = q.shape[2] // k.shape[2]
+    k = jnp.repeat(k, g, axis=2)
+    v = jnp.repeat(v, g, axis=2)
+    s = jnp.einsum("bqhd,bthd->bhqt", q, k).astype(jnp.float32) * scale
+    if softcap:
+        s = softcap * jnp.tanh(s / softcap)
+    t = jnp.arange(k.shape[1])[None, :]
+    ok = t < lengths[:, None]
+    if window:
+        ok &= t >= lengths[:, None] - window
+    s = jnp.where(ok[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqt,bthd->bqhd", p, v)
+
+
+# XLA's CPU dot kernels sum the grouped and the repeated contractions in
+# different orders: float32 agrees to an ulp or two, bfloat16 to one ulp
+REPEAT_TOL = {jnp.float32: dict(rtol=1e-6, atol=1e-6),
+              jnp.bfloat16: dict(rtol=2 ** -7, atol=2 ** -7)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("cap", [None, 30.0])
+@pytest.mark.parametrize("window", [None, 48])
+@pytest.mark.parametrize("g", [1, 2, 4, 7])
+def test_ref_decode_attention_matches_repeat_formula(rng, g, window, cap,
+                                                     dtype):
+    b, t, kv, hd = 4, 192, 2, 64
+    h = kv * g
+    q = _rand(rng, (b, 1, h, hd), dtype)
+    k = _rand(rng, (b, t, kv, hd), dtype)
+    v = _rand(rng, (b, t, kv, hd), dtype)
+    lengths = jnp.asarray([1, t, 37, 150], jnp.int32)
+    scale = 1.0 / np.sqrt(hd)
+    want = _repeat_then_attend(q, k, v, lengths, window, cap, scale)
+    got = ref.decode_attention(q, k, v, lengths=lengths, window=window,
+                               softcap=cap, scale=scale)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        **REPEAT_TOL[dtype])
+
+
+def test_ref_decode_attention_does_not_repeat_kv():
+    """No tensor of the lowered decode attention carries the cache's time
+    axis with all H query heads, flat or as KV x G: each group reads its
+    KV head in place."""
+    b, t, h, kv, hd = 2, 256, 56, 8, 128
+    g = h // kv
+    spec = jax.ShapeDtypeStruct
+    text = jax.jit(lambda q, k, v, n: ref.decode_attention(
+        q, k, v, lengths=n, scale=hd ** -0.5)).lower(
+        spec((b, 1, h, hd), jnp.bfloat16), spec((b, t, kv, hd), jnp.bfloat16),
+        spec((b, t, kv, hd), jnp.bfloat16), spec((b,), jnp.int32)).as_text()
+    shapes = [tuple(map(int, m.rstrip("x").split("x")))
+              for m in re.findall(r"tensor<((?:\d+x)+)", text)]
+    assert any(s == (b, t, kv, hd) for s in shapes)      # the cache itself
+    for s in shapes:
+        if t in s and hd in s:
+            assert h not in s and not (kv in s and g in s), s
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
