@@ -2,11 +2,11 @@
 
 In one process: the compile cache is placed, the weights are made on the
 device from the seed, the program's ``ServingEngine`` is built at the
-cell's sizes and warmed on exactly the programs its traffic will use, a
-closed loop of clients drives it through a lead-in and then the measured
-window, and the output check runs once the window has closed.  With
-``trace`` a profiler trace of a steady part of the window gives the
-per-layer metrics.
+cell's sizes and warmed on exactly the programs its traffic will use, the
+mix's loop of clients (closed, or open at a fixed rate) drives it through
+a lead-in and then the measured window, and the output check runs once the
+window has closed.  With ``trace`` a profiler trace of a steady part of
+the window gives the per-layer metrics.
 
 Metrics are found by name: ``metrics/<name>.py`` holds a ``read(m)`` that
 takes a :class:`Measured` and returns a number, or None where it finds
@@ -16,8 +16,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
-import os
 import shutil
 import sys
 import tempfile
@@ -44,8 +42,8 @@ class Measured:
     generated: int = 0                 # tokens served in the window
     steps: int = 0                     # batched decode steps in the window
     completed: list = dataclasses.field(default_factory=list)
-    # (seconds from submit to finish, tokens served) of each request
-    # finished in the window
+    # (seconds from when it was due to be sent to its finish, tokens
+    # served) of each request finished in the window
     trace: tracereduce.Reduced | None = None
     trace_generated: int = 0
     trace_steps: int = 0
@@ -64,6 +62,7 @@ class Clients:
         self.inflight = [None] * loop.clients
         self.all: list = []
         self.finished: list = []
+        self.due: dict = {}        # request id -> when it was due to be sent
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._run, daemon=True)
 
@@ -71,6 +70,7 @@ class Clients:
         prompt, n_out = self.loop.next(c)
         req = self.engine.submit(prompt, max_new_tokens=n_out)
         self.inflight[c] = req
+        self.due[req.rid] = req.submit_t
         self.all.append(req)
 
     def _run(self) -> None:
@@ -97,13 +97,42 @@ class Clients:
         return {r.rid: len(r.out_tokens) for r in list(self.all)}
 
 
+class Arrivals(Clients):
+    """An open loop on one thread: each request is sent when it is due,
+    ``loop.gap`` after the one before, however many are unanswered; those
+    that find every slot busy wait in the engine's inbox."""
+
+    def _run(self) -> None:
+        j, due, pending = 0, time.perf_counter(), []
+        while not self._stop.is_set():
+            while due <= time.perf_counter():
+                prompt, n_out = self.loop.request(j)
+                req = self.engine.submit(prompt, max_new_tokens=n_out)
+                self.due[req.rid] = due
+                self.all.append(req)
+                pending.append(req)
+                due += self.loop.gap(j)
+                j += 1
+            still = []
+            for req in pending:
+                (self.finished if req.done.is_set() else still).append(req)
+            pending = still
+            time.sleep(max(0.0, min(POLL_S, due - time.perf_counter())))
+
+
+#: the requests and who sends them, by the mix's ``loop``
+LOOPS = {"closed": (loadgen.ClosedLoop, Clients),
+         "open": (loadgen.OpenLoop, Arrivals)}
+
+
+def waiting(reqs: list, t: float) -> int:
+    """Requests submitted by ``t`` and not yet taken into a slot then."""
+    return sum(1 for r in reqs
+               if r.submit_t <= t and not 0.0 < r.admit_t <= t)
+
+
 def _metric_reader(name: str):
-    path = os.path.join(spec.HERE, "metrics", f"{name}.py")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(spec.HERE / "metrics" / f"{name}.py").read
 
 
 def cell_metrics(bench: dict, cell: str, trace: bool) -> list[dict]:
@@ -158,6 +187,7 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
     from repro.models import model as model_lib
     from repro.serve.engine import ServingEngine
 
+    t_run = time.perf_counter()
     compile_cache.enable()
     conf, sizes, mix = cell.config, cell.sizes, cell.traffic
     max_batch, max_len = sizes["max_batch"], sizes["max_len"]
@@ -170,12 +200,13 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
     if got != want:
         raise ValueError("the weights do not match the program's layout")
     _memory_note(device, "with the weights")
+    t_weights = time.perf_counter()
 
     engine = ServingEngine(cfg, weights, max_batch=max_batch,
                            max_len=max_len)
     engine.start()
-    loop = loadgen.ClosedLoop(mix, conf["vocab_size"], max_len, max_batch,
-                              seed)
+    requests, senders = LOOPS[mix["loop"]]
+    loop = requests(mix, conf["vocab_size"], max_len, max_batch, seed)
     # warm every program the window will run, through the engine itself
     warm = [engine.submit(loop.prompt(i, n, stream=3), max_new_tokens=2)
             for i, n in enumerate(warm_lengths(cfg, loop, max_len))]
@@ -184,8 +215,9 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
         if req.error is not None or not req.done.is_set():
             raise RuntimeError(f"warm-up failed: {req.error}")
     _memory_note(device, "after the warm-up")
+    t_warm = time.perf_counter()
 
-    clients = Clients(engine, loop)
+    clients = senders(engine, loop)
     clients.start()
     lead, t_lead = mix["lead_in_turns"] * loop.clients, time.perf_counter()
     while len(clients.finished) < lead and engine.error is None:
@@ -211,15 +243,22 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
     done = [r for r in clients.all if r.done.is_set() and r.error is None
             and t0 <= r.finish_t < t1]
     failed = [r for r in clients.all if r.error is not None]
-    m.completed = [(r.finish_t - r.submit_t, len(r.out_tokens))
+    m.completed = [(r.finish_t - clients.due[r.rid], len(r.out_tokens))
                    for r in done]
+    due = [r for r in clients.all if t0 <= clients.due[r.rid] < t1]
+    late_ms = max(((r.submit_t - clients.due[r.rid]) * 1e3 for r in due),
+                  default=0.0)
+    load = {"due_in_window": len(due),
+            "waiting_at_open": waiting(clients.all, t0),
+            "waiting_at_close": waiting(clients.all, t1),
+            "late_ms_max": late_ms}
     finished = [(r.prompt, list(r.out_tokens)) for r in done]
     peak_bytes = _device_peak_bytes(device)
     _memory_note(device, "when the window closed")
     # free the program's device state: everything but the weights, which
     # are the benchmark's own (the engine holds every admission's batch-1
     # cache in its task graph; see PERF.md)
-    del engine, clients, done
+    del engine, clients, done, due
     gc.collect()
     keep = {id(a) for a in jax.tree.leaves(weights)}
     for a in jax.live_arrays():
@@ -264,6 +303,7 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
         dev["busy_s"] = tracereduce.busy_ns(m.trace) * 1e-9
         dev["window_s"] = m.trace.window_s
         result["breakdown"] = tracereduce.breakdown(m.trace)
+    result["load"] = load
     if control:
         result["readings"] = readings
     result["compared"] = compared
@@ -271,8 +311,14 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
     notes = [
         f"window {m.window_s:.3f} s: {len(m.completed)} requests finished, "
         f"{m.generated} tokens, {m.steps} decode steps; "
-        f"set-up {setup_s:.3f} s",
+        f"set-up {setup_s:.3f} s: {t_run - t_start:.3f} to the harness, "
+        f"{t_weights - t_run:.3f} weights, {t_warm - t_weights:.3f} engine "
+        f"and warm-up, {t0 - t_warm:.3f} lead-in",
         f"programs loaded or compiled inside the window: {loaded}",
+        f"{load['due_in_window']} requests due in the window; waiting for "
+        f"a slot: {load['waiting_at_open']} when it opened, "
+        f"{load['waiting_at_close']} when it closed; those due in it were "
+        f"sent late by at most {late_ms:.3f} ms",
         f"engine error: {engine_error!r}; failed requests: {len(failed)}",
         f"checked {got['requests']} requests, {got['tokens']} served "
         f"tokens; the reference's first choice differs at a share of "

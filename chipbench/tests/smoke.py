@@ -47,3 +47,15 @@ def smoke_cell(name: str, dtype: str = "float32", max_batch: int = 4,
                  limits={"max_logit_gap": limit})
     return spec.Cell(name=name, chips=1, config=conf, traffic=cell.traffic,
                      sizes=sizes)
+
+
+def hybrid_config(conf: dict) -> dict:
+    """A smoke-width hybrid of the ``stack`` family: mamba2 layers beside a
+    shared attention block, as the program's hybrids have them."""
+    return dict(conf, name="hybrid-smoke", num_hidden_layers=3,
+                tie_word_embeddings=True, hidden_act="gelu",
+                mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+                mamba_headdim=16, chunk_size=32, time_step_min=0.001,
+                time_step_max=0.1, time_step_floor=1e-4,
+                block_pattern=[{"kind": "mamba2", "mlp": "none"}] * 2
+                + [{"kind": "attn", "mlp": "glu", "shared": True}])

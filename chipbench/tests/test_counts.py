@@ -1,13 +1,13 @@
-"""The operation and byte counts, checked by hand for one layer of each
-configuration.
+"""The ``stack`` family's operation and byte counts, checked by hand for
+one layer of each configuration.
 
     python -m pytest chipbench/tests -q
 """
 import smoke  # noqa: F401
-import counts
 import spec
 
 DS = spec.load_json(spec.HERE / "configs" / "deepseek-coder-33b-8L.json")
+counts = spec.family(DS, "counts")
 #: a hybrid at the widths of the program's zamba2-2.7b, for the mamba2 and
 #: shared-block counts (no cell of the benchmark runs it yet)
 ZB = {"hidden_size": 2560, "intermediate_size": 10240,

@@ -53,13 +53,7 @@ def test_hybrid_runs_and_checks():
     exact-length prefills a ladder of prompt lengths reaches.  No cell runs
     a hybrid until the engine stops keeping a cache per admission."""
     base = smoke.smoke_cell(CELLS[0])
-    conf = dict(base.config, name="hybrid-smoke", num_hidden_layers=3,
-                tie_word_embeddings=True, hidden_act="gelu",
-                mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
-                mamba_headdim=16, chunk_size=32, time_step_min=0.001,
-                time_step_max=0.1, time_step_floor=1e-4,
-                block_pattern=[{"kind": "mamba2", "mlp": "none"}] * 2
-                + [{"kind": "attn", "mlp": "glu", "shared": True}])
+    conf = smoke.hybrid_config(base.config)
     mix = dict(base.traffic,
                prompt=dict(base.traffic["prompt"], snap_to=[32, 64, 128]))
     cell = spec.Cell(name=base.name, chips=1, config=conf, traffic=mix,
