@@ -94,6 +94,17 @@ def weight_bytes(conf: dict) -> int:
     return n * 2                      # bfloat16
 
 
+def mlp_weight_bytes(conf: dict) -> int:
+    """The GLU MLPs' weights a decode step reads: the gate, up and down
+    projections of each layer once (a shared block once, as in
+    :func:`weight_bytes`), without the norm before them."""
+    d, ff = conf["hidden_size"], conf["intermediate_size"]
+    reps = conf["num_hidden_layers"] // len(conf["block_pattern"])
+    n = sum(3 * d * ff * (1 if block.get("shared") else reps)
+            for block in conf["block_pattern"] if block["mlp"] == "glu")
+    return n * 2                      # bfloat16
+
+
 def kv_bytes_per_position(conf: dict) -> int:
     """Key and value bytes of one position over all attention layers."""
     n_attn = sum(1 for b in _applications(conf) if b["kind"] == "attn")

@@ -10,7 +10,11 @@ the window gives the per-layer metrics.
 
 Metrics are found by name: ``metrics/<name>.py`` holds a ``read(m)`` that
 takes a :class:`Measured` and returns a number, or None where it finds
-nothing to read.
+nothing to read.  Besides the trace, a reader gets the engine's counters
+(every ``n_...`` attribute, over the window and over the traced part) and,
+in traced runs, the decode program's op names by instruction, so that
+``scopes.ns_under`` times any named scope: a new metric of a new scope or
+counter is a new file.
 """
 from __future__ import annotations
 
@@ -22,8 +26,11 @@ import tempfile
 import threading
 import time
 
+import numpy as np
+
 import check
 import loadgen
+import scopes
 import spec
 import tracereduce
 
@@ -51,6 +58,13 @@ class Measured:
     # positions attended by each token served in the traced window
     prefill_lens: list = dataclasses.field(default_factory=list)
     # real tokens of each prefill that ran in the traced window
+    counters: dict = dataclasses.field(default_factory=dict)
+    trace_counters: dict = dataclasses.field(default_factory=dict)
+    # the change of each engine counter (engine_counters) over the window
+    # and over the traced part
+    op_names: dict = dataclasses.field(default_factory=dict)
+    # traced runs only: program -> {HLO instruction: op_name} of the
+    # compiled program that ran ("decode_fn")
 
 
 class Clients:
@@ -129,6 +143,41 @@ def waiting(reqs: list, t: float) -> int:
     """Requests submitted by ``t`` and not yet taken into a slot then."""
     return sum(1 for r in reqs
                if r.submit_t <= t and not 0.0 < r.admit_t <= t)
+
+
+def engine_counters(engine) -> dict[str, int]:
+    """Every attribute of the engine whose name starts with ``n_`` and that
+    holds a whole number (a 0-d integer array is read with ``int()``)."""
+    out = {}
+    for k, v in vars(engine).items():
+        if not k.startswith("n_") or isinstance(v, bool):
+            continue
+        if isinstance(v, int):
+            out[k] = v
+        elif getattr(v, "shape", None) == () and np.issubdtype(
+                getattr(v, "dtype", np.float32), np.integer):
+            out[k] = int(v)
+    return out
+
+
+def deltas(c0: dict, c1: dict) -> dict[str, int]:
+    return {k: c1[k] - c0[k] for k in c1 if k in c0}
+
+
+def decode_call(engine) -> tuple:
+    """The engine's jitted decode function and the shapes of its arguments
+    (params, tokens, cache, positions).  Shapes with no placement lower to
+    the very module the engine's calls on one device did, so its compile
+    is found in the cache."""
+    import jax
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    b = engine.max_batch
+    return engine._decode, (jax.tree.map(shape, engine.params),
+                            jax.ShapeDtypeStruct((b, 1), np.int32),
+                            jax.tree.map(shape, engine.cache),
+                            jax.ShapeDtypeStruct((b,), np.int32))
 
 
 def _metric_reader(name: str):
@@ -227,19 +276,21 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
 
     m = Measured(conf=conf, sizes=sizes, peaks=peaks)
     cc0 = compile_cache.stats()
-    gen0, steps0 = engine.n_generated, engine.n_decode_steps
+    c0 = engine_counters(engine)
     t0 = time.perf_counter()
     setup_s = t0 - t_start
     traced = _traced(m, engine, clients, seconds) if trace else None
     time.sleep(max(0.0, t0 + seconds - time.perf_counter()))
     t1 = time.perf_counter()
-    gen1, steps1 = engine.n_generated, engine.n_decode_steps
+    c1 = engine_counters(engine)
     cc1 = compile_cache.stats()
     clients.stop()
     engine.stop()
     engine_error = engine.error
 
-    m.window_s, m.generated, m.steps = t1 - t0, gen1 - gen0, steps1 - steps0
+    m.window_s, m.counters = t1 - t0, deltas(c0, c1)
+    m.generated = m.counters["n_generated"]
+    m.steps = m.counters["n_decode_steps"]
     done = [r for r in clients.all if r.done.is_set() and r.error is None
             and t0 <= r.finish_t < t1]
     failed = [r for r in clients.all if r.error is not None]
@@ -253,6 +304,7 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
             "waiting_at_close": waiting(clients.all, t1),
             "late_ms_max": late_ms}
     finished = [(r.prompt, list(r.out_tokens)) for r in done]
+    decode = decode_call(engine) if trace else None
     peak_bytes = _device_peak_bytes(device)
     _memory_note(device, "when the window closed")
     # free the program's device state: everything but the weights, which
@@ -264,6 +316,14 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
     for a in jax.live_arrays():
         if id(a) not in keep:
             a.delete()
+    if decode is not None:
+        # the compile cache hands back the very program that ran
+        fn, args = decode
+        misses = compile_cache.stats()["misses"]
+        m.op_names = {"decode_fn": scopes.op_names(
+            fn.lower(*args).compile().as_text())}
+        misses = compile_cache.stats()["misses"] - misses
+        del decode, fn, args
     if traced is not None:
         try:
             m.trace = tracereduce.reduce(tracereduce.find_xplane(traced))
@@ -323,8 +383,19 @@ def run(cell: spec.Cell, bench: dict, seed: int, seconds: float,
         f"checked {got['requests']} requests, {got['tokens']} served "
         f"tokens; the reference's first choice differs at a share of "
         f"{got['mismatch_share']:.4f}",
-    ] + [f"{k} {v['value']!r} limit {v['limit']!r}"
-         for k, v in compared.items()]
+    ]
+    if m.trace is not None:
+        names = m.op_names["decode_fn"]
+        named = {k: v for k, v in names.items() if v}
+        notes.append(
+            f"decode_fn op names: {len(names)} instructions, {len(named)} "
+            f"with an op name, compiled anew for them: {misses}; share of "
+            f"its leaf device time on them: "
+            f"{scopes.named_share(m.trace, names, 'decode_fn')!r}, on those "
+            f"with an op name: "
+            f"{scopes.named_share(m.trace, named, 'decode_fn')!r}")
+    notes += [f"{k} {v['value']!r} limit {v['limit']!r}"
+              for k, v in compared.items()]
     return result, notes
 
 
@@ -341,13 +412,15 @@ def _traced(m: Measured, engine, clients: Clients, seconds: float) -> str:
     try:
         with jax.profiler.TraceAnnotation(tracereduce.WINDOW):
             served0 = clients.served()
-            gen0, steps0 = engine.n_generated, engine.n_decode_steps
+            c0 = engine_counters(engine)
             time.sleep(trace_s)
-            gen1, steps1 = engine.n_generated, engine.n_decode_steps
+            c1 = engine_counters(engine)
             served1 = clients.served()
     finally:
         jax.profiler.stop_trace()
-    m.trace_generated, m.trace_steps = gen1 - gen0, steps1 - steps0
+    m.trace_counters = deltas(c0, c1)
+    m.trace_generated = m.trace_counters["n_generated"]
+    m.trace_steps = m.trace_counters["n_decode_steps"]
     prompts = {r.rid: len(r.prompt) for r in list(clients.all)}
     for rid, n1 in served1.items():
         n0 = served0.get(rid, 0)

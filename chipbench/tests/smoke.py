@@ -59,3 +59,17 @@ def hybrid_config(conf: dict) -> dict:
                 time_step_max=0.1, time_step_floor=1e-4,
                 block_pattern=[{"kind": "mamba2", "mlp": "none"}] * 2
                 + [{"kind": "attn", "mlp": "glu", "shared": True}])
+
+
+def kept_measured(monkeypatch) -> list:
+    """Every ``harness.Measured`` that runs make from now on, in a list."""
+    import dataclasses
+    import harness
+    made = []
+
+    @dataclasses.dataclass
+    class Kept(harness.Measured):
+        def __post_init__(self):
+            made.append(self)
+    monkeypatch.setattr(harness, "Measured", Kept)
+    return made

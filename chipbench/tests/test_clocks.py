@@ -1,6 +1,7 @@
 """One clock for the device plane, the host plane and ``perf_counter``
 (``clocks.py``), and device time by named scope (``scopes.py``): on the
 recorded v5e trace of ``test_trace.py`` and on small hand-made inputs."""
+import types
 from pathlib import Path
 
 import pytest
@@ -8,6 +9,7 @@ import pytest
 import smoke  # noqa: F401
 import clocks
 import scopes
+import spec
 import tracereduce as tr
 
 SMALL = str(Path(__file__).resolve().parent / "data" / "small.xplane.pb")
@@ -193,8 +195,106 @@ def test_scope_times_skip_containers_and_other_programs():
     assert runs == 2
     assert dict(by_scope) == {"attn/decode_attention": 80, "mlp": 35,
                               "": 15, "head": 10}
-    assert scopes.within(by_scope, "decode_attention") == 80
-    assert scopes.within(by_scope, "attn") == 80
+    m = types.SimpleNamespace(trace=red, op_names={"decode_fn": names})
+    assert scopes.ns_under(m, "decode_fn", "decode_attention") == (80, 2)
+    assert scopes.ns_under(m, "decode_fn", "attn") == (80, 2)
     # leaves only: the loop's 90 ns enclose its body's 80
     assert sum(by_scope.values()) <= sum(
         tr.program_times(red, "decode_fn"))
+
+
+#: a decode program whose layer scan holds a scope that ``SCOPES`` does not
+#: list (``probe_gate``, inside ``mlp``), a slice under ``layers`` alone, a
+#: copy under no scope, a copy the compiler made with no op name, and the
+#: scan's loop itself under ``layers``
+SCAN_HLO = """\
+HloModule jit_decode_fn, entry_computation_layout={...}
+
+%body (p: (s32[], bf16[8])) -> (s32[], bf16[8]) {
+  %fusion.4 = bf16[8]{0} fusion(bf16[8]{0} %g), kind=kLoop, calls=%f4, metadata={op_name="jit(decode_fn)/layers/while/body/closed_call/mlp/dot_general"}
+  %fusion.5 = bf16[8]{0} fusion(bf16[8]{0} %fusion.4), kind=kLoop, calls=%f5, metadata={op_name="jit(decode_fn)/layers/while/body/closed_call/mlp/probe_gate/mul"}
+  ROOT %dynamic-slice.2 = bf16[8]{0} dynamic-slice(bf16[8,8]{1,0} %w, s32[] %i), metadata={op_name="jit(decode_fn)/layers/while/body/dynamic_slice"}
+}
+
+ENTRY %main (x: bf16[8]) -> bf16[8] {
+  %while.4 = (s32[], bf16[8]{0}) while((s32[], bf16[8]{0}) %t), condition=%cond, body=%body, metadata={op_name="jit(decode_fn)/layers/while"}
+  %copy.1 = bf16[8]{0} copy(bf16[8]{0} %c), metadata={op_name="jit(decode_fn)/copy"}
+  %copy.7 = bf16[8]{0} copy(bf16[8]{0} %copy.1)
+  ROOT %fusion.9 = s32[16]{0} fusion(bf16[8]{0} %w), kind=kLoop, metadata={op_name="jit(decode_fn)/head/dot_general"}
+}
+"""
+
+MS = 1_000_000
+
+
+def _op(start_ms, end_ms, name, opcode="fusion"):
+    return (start_ms * MS, end_ms * MS,
+            f"%{name} = bf16[8]{{0}} {opcode}(bf16[8]{{0}} %x)")
+
+
+#: two decode runs of 30 ms around a prefill run; in each, 7 ms of
+#: ``mlp`` and 3 of ``mlp/probe_gate``, 2 of the slice and 2 of the copy,
+#: 1 of the head; the first also 1 ms of an instruction the program does
+#: not hold, and the loop enclosing its body; the second 1 ms of the copy
+#: with no op name
+SCAN_TRACE = tr.Reduced(lo=0, hi=70 * MS, host=[], modules=[
+    (0, 30 * MS, "jit_decode_fn(1)"), (30 * MS, 40 * MS, "jit_prefill_fn(2)"),
+    (40 * MS, 70 * MS, "jit_decode_fn(1)")], ops=sorted([
+        _op(0, 29, "while.4", "while"), _op(0, 7, "fusion.4"),
+        _op(7, 10, "fusion.5"), _op(10, 12, "dynamic-slice.2",
+                                    "dynamic-slice"),
+        _op(12, 14, "copy.1", "copy"), _op(14, 15, "unknown.1", "add"),
+        _op(29, 30, "fusion.9"), _op(30, 40, "fusion.4"),
+        _op(40, 47, "fusion.4"), _op(47, 50, "fusion.5"),
+        _op(50, 52, "dynamic-slice.2", "dynamic-slice"),
+        _op(52, 54, "copy.1", "copy"), _op(54, 55, "copy.7", "copy"),
+        _op(69, 70, "fusion.9")]))
+
+
+def _measured(trace=SCAN_TRACE, names=True):
+    import harness
+    conf = spec.load_json(spec.HERE / "configs" / "deepseek-coder-33b-8L.json")
+    # a bandwidth at which the MLP weights (6,606,028,800 bytes) take 8 ms
+    peaks = {"hbm_bytes_per_s": 6_606_028_800 / 0.008,
+             "bf16_flops_per_s": 1e15}
+    return harness.Measured(
+        conf=conf, sizes={}, peaks=peaks, trace=trace,
+        op_names={"decode_fn": scopes.op_names(SCAN_HLO)} if names else {})
+
+
+@pytest.mark.parametrize("name,ns", [
+    ("probe_gate", 6 * MS),          # not in SCOPES
+    ("mlp", 20 * MS),
+    ("layers", 24 * MS),             # without the 29 ms of the loop
+    ("head", 2 * MS),
+    ("attn", 0),
+])
+def test_ns_under_reads_any_named_scope(name, ns):
+    assert "probe_gate" not in scopes.SCOPES
+    assert scopes.ns_under(_measured(), "decode_fn", name) == (ns, 2)
+
+
+@pytest.mark.parametrize("metric,value", [
+    # (2 + 2 ms of the slice) + (2 + 2 + 1 of the copies + 1 unknown) / 2
+    ("decode_copy_ms", 5.0),
+    # 8 ms of weights at the peak over (14 + 6 ms of mlp) / 2 runs
+    ("mlp_roofline", 80.0),
+])
+def test_scope_readers_by_hand(metric, value):
+    import harness
+    read = harness._metric_reader(metric)
+    assert read(_measured()) == pytest.approx(value, rel=1e-12)
+    # nothing to read: no trace, or no op names of the decode program
+    assert read(_measured(trace=None)) is None
+    assert read(_measured(names=False)) is None
+
+
+def test_named_share_counts_unnamed_instructions():
+    names = scopes.op_names(SCAN_HLO)
+    assert names["copy.7"] == "" and scopes.scope(names["copy.7"]) == ""
+    # 32 ms of leaves in the decode runs: 1 on unknown.1, which the program
+    # does not hold, and 1 on copy.7, which has no op name
+    assert scopes.named_share(SCAN_TRACE, names, "decode_fn") == 31 / 32
+    with_name = {k: v for k, v in names.items() if v}
+    assert scopes.named_share(SCAN_TRACE, with_name, "decode_fn") == 30 / 32
+    assert scopes.named_share(SCAN_TRACE, names, "absent_fn") is None

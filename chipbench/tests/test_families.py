@@ -20,6 +20,7 @@ import pytest
 import smoke
 import counts
 import reference
+import scopes
 import spec
 
 DS = spec.load_json(spec.HERE / "configs" / "deepseek-coder-33b-8L.json")
@@ -215,20 +216,26 @@ ATTN_ONLY = {
 }
 
 
-def test_a_new_family_runs_through_the_harness(tmp_path, monkeypatch):
-    """A family in a directory of its own, and a configuration that names
-    it, run through the whole harness and pass the output check."""
-    import harness
+def _attn_only_cell(tmp_path, monkeypatch) -> spec.Cell:
+    """The chat cell at smoke widths, of the attention-only family written
+    into a directory of its own."""
     home = tmp_path / "families" / "attn-only"
     home.mkdir(parents=True)
     for name, text in ATTN_ONLY.items():
         (home / name).write_text(textwrap.dedent(text).lstrip())
     monkeypatch.setattr(spec, "FAMILIES", tmp_path / "families")
-
     base = smoke.smoke_cell("deepseek-coder-33b-8L.chat")
     conf = dict(base.config, family="attn-only", name="attn-only-smoke")
-    cell = spec.Cell(name=base.name, chips=1, config=conf,
+    return spec.Cell(name=base.name, chips=1, config=conf,
                      traffic=base.traffic, sizes=base.sizes)
+
+
+def test_a_new_family_runs_through_the_harness(tmp_path, monkeypatch):
+    """A family in a directory of its own, and a configuration that names
+    it, run through the whole harness and pass the output check."""
+    import harness
+    cell = _attn_only_cell(tmp_path, monkeypatch)
+    conf = cell.config
     assert spec.model_config(conf).groups[0].pattern[0].mlp == "none"
     bench = spec.load_json(smoke.ROOT / "BENCHMARK.json")
     result, notes = harness.run(cell, bench, SEED, 2.0, False,
@@ -239,3 +246,88 @@ def test_a_new_family_runs_through_the_harness(tmp_path, monkeypatch):
     # the family's counts, through the names the metric readers call
     assert counts.decode_flops(conf, 10) > counts.decode_flops(conf, 1)
     assert counts.weight_bytes(conf) > 0
+
+
+#: a per-layer metric a family's PR would add as ``metrics/<name>.py``: the
+#: device time under a scope the program names for the family's own kernel
+#: (no entry of ``scopes.SCOPES``), per decode step the engine counted
+PROBE_METRIC = '''
+    import scopes
+
+
+    def read(m):
+        ns, runs = scopes.ns_under(m, "decode_fn", "probe_kernel")
+        steps = m.trace_counters.get("n_decode_steps", 0)
+        return ns * 1e-6 / steps if runs and steps else None
+'''
+
+METADATA_KEY = "jax_compilation_cache_include_metadata_in_key"
+
+
+def test_a_new_family_brings_a_scope_and_counter_metric(tmp_path,
+                                                         monkeypatch):
+    """A family and a metric of its own, from new files alone: the metric
+    reads the device time under a scope that no benchmark file names, and
+    an engine counter, through a traced run of the whole harness.  The CPU
+    gives no device plane, so the trace is made by hand from the op names
+    the harness took from the compiled program: 5 ns on each instruction
+    under the scope, 7 ns on one outside it."""
+    import harness
+    import tracereduce
+    from repro.kernels import ops
+    cell = _attn_only_cell(tmp_path, monkeypatch)
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "probe_kernel_ms.py").write_text(
+        textwrap.dedent(PROBE_METRIC).lstrip())
+    monkeypatch.setattr(spec, "HERE", tmp_path)
+    bench = {"end_to_end": [], "per_layer": [{
+        "name": "probe_kernel_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "ops and kernels",
+        "moves": "tokens_per_s"}]}
+
+    # the family's kernel as the program would bring it, under its own name
+    attend = ops.decode_attention
+
+    def probe_kernel(*args, **kw):
+        with jax.named_scope("probe_kernel"):
+            return attend(*args, **kw)
+    monkeypatch.setattr(ops, "decode_attention", probe_kernel)
+    assert "probe_kernel" not in scopes.SCOPES
+
+    names = {}
+    op_names = scopes.op_names
+
+    def keep(hlo_text):
+        names.update(op_names(hlo_text))
+        return names
+    monkeypatch.setattr(scopes, "op_names", keep)
+    under = []
+
+    def by_hand(path):
+        under.extend(i for i, n in names.items()
+                     if "probe_kernel" in n.split("/"))
+        head = next(i for i, n in names.items() if "head" in n.split("/"))
+        ops_ = [(10 * k, 10 * k + 5, f"%{i} = f32[] add()")
+                for k, i in enumerate(under)] + [(0, 7, f"%{head} = add()")]
+        return tracereduce.Reduced(lo=0, hi=10 ** 6, ops=sorted(ops_),
+                                   host=[],
+                                   modules=[(0, 10 ** 5, "jit_decode_fn(1)")])
+    monkeypatch.setattr(tracereduce, "find_xplane", lambda d: d)
+    monkeypatch.setattr(tracereduce, "reduce", by_hand)
+    made = smoke.kept_measured(monkeypatch)
+
+    # the compile cache leaves op names out of its key; here only the names
+    # differ from a program compiled before, so they go into it
+    was = getattr(jax.config, METADATA_KEY)
+    jax.config.update(METADATA_KEY, True)
+    try:
+        result, notes = harness.run(cell, bench, SEED, 3.0, True,
+                                    time.perf_counter(), jax.devices()[0],
+                                    smoke.PEAKS)
+    finally:
+        jax.config.update(METADATA_KEY, was)
+    assert result["correct"], notes
+    (m,) = made
+    assert under and m.trace_counters["n_decode_steps"] > 0
+    assert result["metrics"]["probe_kernel_ms"]["value"] == pytest.approx(
+        5 * len(under) * 1e-6 / m.trace_counters["n_decode_steps"])

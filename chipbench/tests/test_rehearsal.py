@@ -47,6 +47,57 @@ def test_each_mix_runs_and_checks(name):
     assert notes[-1].startswith("max_logit_gap ")
 
 
+@pytest.mark.parametrize("trace", [False, True], ids=["trace0", "trace1"])
+def test_counters_and_op_names_reach_the_readers(monkeypatch, trace):
+    """Every ``n_...`` whole number of the engine reaches the readers as its
+    change over the window (and over the traced part); the decode
+    program's op names only in a traced run, with the model's scopes."""
+    import numpy as np
+    import harness
+    import scopes
+    from repro.serve import engine as engine_lib
+    iterate = engine_lib.ServingEngine._iterate
+
+    def probed(self):
+        if not hasattr(self, "n_probe"):
+            self.n_probe, self.n_ratio, self.n_flag = 10**6, 0.5, True
+            self.n_probe_0d = np.asarray(10**6, np.int32)
+        iterate(self)
+        self.n_probe += 2
+        self.n_probe_0d = self.n_probe_0d + 3
+    monkeypatch.setattr(engine_lib.ServingEngine, "_iterate", probed)
+    made = smoke.kept_measured(monkeypatch)
+    bench = spec.load_json(smoke.ROOT / "BENCHMARK.json")
+    result, notes = harness.run(smoke.smoke_cell(CELLS[0]), bench,
+                                2**31 + 11, 3.0, trace, time.perf_counter(),
+                                jax.devices()[0], smoke.PEAKS)
+    assert result["correct"], notes
+    (m,) = made
+    parts = [(m.counters, m.steps)]
+    if trace:
+        parts.append((m.trace_counters, m.trace_steps))
+    else:
+        assert m.trace_counters == {}
+    for counted, steps in parts:
+        assert set(counted) == {"n_decode_steps", "n_generated", "n_probe",
+                                "n_probe_0d"}
+        assert counted["n_decode_steps"] == steps > 0
+        # changes, not readings; the loop passes at least once a step, and
+        # a reading may fall between two counts of one pass
+        passes = counted["n_probe"] // 2
+        assert counted["n_probe"] == 2 * passes and passes < 10**6
+        assert abs(counted["n_probe_0d"] - 3 * passes) <= 3
+        assert counted["n_probe_0d"] % 3 == 0 and passes >= steps - 1
+    assert m.counters["n_generated"] == m.generated
+    if not trace:
+        assert m.op_names == {}
+        return
+    found = {p for n in m.op_names["decode_fn"].values()
+             for p in scopes.scope(n).split("/")}
+    assert {"layers", "attn", "mlp"} <= found
+    assert any(line.startswith("decode_fn op names: ") for line in notes)
+
+
 def test_hybrid_runs_and_checks():
     """mamba2 layers beside a shared attention block, as the program's
     hybrids have them: the reference's recurrent path, and warm-up of the
