@@ -6,8 +6,13 @@ Three execution modes share one code path:
   * prefill: full sequence, causal mask, writes the KV cache.
   * decode:  q_len == 1 against a pre-filled cache at ``pos``.
 
-Caches are plain dicts of arrays so they stack cleanly under the
-scan-over-layers used by :mod:`repro.models.model`.
+Caches are plain dicts of arrays.  A group of layers keeps each slot's
+caches stacked over its repeats, ``(R, B, T, ...)``, in the carry of its
+layer scan (:func:`repro.models.blocks.apply_group`): GQA and MLA take that
+stack and their layer's index, write their new rows into it in place and
+read their layer's slice back, so a step moves only the rows it writes
+and the cache its attention reads.  Cross-attention takes its layer's own
+cache and returns it whole.
 """
 from __future__ import annotations
 
@@ -65,11 +70,16 @@ def _attn_scale(cfg: ModelConfig) -> float:
 
 def apply_attn(params: Params, cfg: ModelConfig, spec: LayerSpec,
                x: jax.Array, positions: jax.Array,
-               cache: Params | None = None) -> tuple[jax.Array, Params | None]:
+               cache: Params | None = None,
+               layer: jax.Array | int | None = None
+               ) -> tuple[jax.Array, Params | None]:
     """x: (B, S, D); positions: (B, S) absolute positions.
 
-    When ``cache`` is given and S > 1 this is prefill (cache written at
-    [0, S)); when S == 1 it is a decode step at ``positions[:, 0]``.
+    ``cache`` is the stack of the group's caches, ``(R, B, T, KV, hd)``
+    leaves, of which this layer is index ``layer``; the stack is returned
+    with this layer's rows written.  When it is given and S > 1 this is
+    prefill (rows [0, S) written); when S == 1 it is a decode step at
+    ``positions[:, 0]``.
     """
     b, s, _ = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -108,12 +118,8 @@ def apply_attn(params: Params, cfg: ModelConfig, spec: LayerSpec,
         return out.reshape(b, s, h * hd) @ hint(params["wo"], "heads_out", "wt_d"), None
 
     if s > 1:  # prefill
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(
-                cache["k"].astype(k.dtype), k, 0, axis=1),
-            "v": jax.lax.dynamic_update_slice_in_dim(
-                cache["v"].astype(v.dtype), v, 0, axis=1),
-        }
+        new_cache = {"k": _write_prefix(cache["k"], layer, k),
+                     "v": _write_prefix(cache["v"], layer, v)}
         out = ops.flash_attention(q, k, v, causal=True, window=window,
                                   softcap=softcap, scale=scale)
         out = hint(out, "batch", "attn_seq", "heads", None)
@@ -122,21 +128,35 @@ def apply_attn(params: Params, cfg: ModelConfig, spec: LayerSpec,
     # decode: write (k, v) at pos then attend to the whole cache with a
     # validity mask (<= pos, > pos - window).
     pos = positions[:, 0]  # (B,)
-    new_cache = {
-        "k": _scatter_time(cache["k"], k[:, 0], pos),
-        "v": _scatter_time(cache["v"], v[:, 0], pos),
-    }
-    out = ops.decode_attention(q, new_cache["k"], new_cache["v"],
+    new_cache = {"k": _write_rows(cache["k"], layer, k[:, 0], pos),
+                 "v": _write_rows(cache["v"], layer, v[:, 0], pos)}
+    out = ops.decode_attention(q, _layer_of(new_cache["k"], layer, k.dtype),
+                               _layer_of(new_cache["v"], layer, v.dtype),
                                lengths=pos + 1, window=window,
                                softcap=softcap, scale=scale)
     out = hint(out, "batch", "attn_seq", "heads", None)
     return out.reshape(b, s, h * hd) @ hint(params["wo"], "heads_out", "wt_d"), new_cache
 
 
-def _scatter_time(buf: jax.Array, val: jax.Array, pos: jax.Array) -> jax.Array:
-    """buf: (B, S, ...), val: (B, ...), pos: (B,) -> buf with val at pos."""
-    b = buf.shape[0]
-    return buf.astype(val.dtype).at[jnp.arange(b), pos].set(val)
+def _write_rows(stack: jax.Array, layer, val: jax.Array,
+                pos: jax.Array) -> jax.Array:
+    """stack: (R, B, T, ...), val: (B, ...), pos: (B,) -> stack with
+    val[b] at [layer, b, pos[b]]."""
+    b = val.shape[0]
+    return stack.at[layer, jnp.arange(b), pos].set(val.astype(stack.dtype))
+
+
+def _write_prefix(stack: jax.Array, layer, val: jax.Array) -> jax.Array:
+    """stack: (R, B, T, ...), val: (B, S, ...) -> stack with val at
+    [layer, :, :S]."""
+    return jax.lax.dynamic_update_slice(
+        stack, val[None].astype(stack.dtype), (layer,) + (0,) * val.ndim)
+
+
+def _layer_of(stack: jax.Array, layer, dtype) -> jax.Array:
+    """The layer's slice (B, T, ...) of a stacked cache, in ``dtype``."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0,
+                                        keepdims=False).astype(dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -237,8 +257,10 @@ def _mla_attend_causal(cfg: ModelConfig, q_nope, q_rope, ckv, krope,
 
 def apply_mla(params: Params, cfg: ModelConfig, spec: LayerSpec,
               x: jax.Array, positions: jax.Array,
-              cache: Params | None = None, *,
+              cache: Params | None = None,
+              layer: jax.Array | int | None = None, *,
               absorbed: bool = False) -> tuple[jax.Array, Params | None]:
+    """As :func:`apply_attn`, with the latent cache (``ckv``, ``krope``)."""
     b, s, _ = x.shape
     m = cfg.mla
     h = cfg.num_heads
@@ -263,26 +285,23 @@ def apply_mla(params: Params, cfg: ModelConfig, spec: LayerSpec,
         return out.reshape(b, s, -1) @ hint(params["wo"], "heads_out", "wt_d"), None
 
     if s > 1:  # prefill
-        new_cache = {
-            "ckv": jax.lax.dynamic_update_slice_in_dim(
-                cache["ckv"].astype(ckv.dtype), ckv, 0, axis=1),
-            "krope": jax.lax.dynamic_update_slice_in_dim(
-                cache["krope"].astype(krope.dtype), krope, 0, axis=1),
-        }
+        new_cache = {"ckv": _write_prefix(cache["ckv"], layer, ckv),
+                     "krope": _write_prefix(cache["krope"], layer, krope)}
         out = _mla_attend_causal(cfg, q_nope, q_rope, ckv, krope,
                                  hint(params["wk_b"], None, "heads_out"), hint(params["wv_b"], None, "heads_out"), absorbed)
         return out.reshape(b, s, -1) @ hint(params["wo"], "heads_out", "wt_d"), new_cache
 
     pos = positions[:, 0]
     new_cache = {
-        "ckv": _scatter_time(cache["ckv"], ckv[:, 0], pos),
-        "krope": _scatter_time(cache["krope"], krope[:, 0], pos),
+        "ckv": _write_rows(cache["ckv"], layer, ckv[:, 0], pos),
+        "krope": _write_rows(cache["krope"], layer, krope[:, 0], pos),
     }
-    t = new_cache["ckv"].shape[1]
+    t = new_cache["ckv"].shape[2]
     mask = jnp.arange(t)[None, None, :] <= pos[:, None, None]  # (B,1,T)
-    out = _mla_attend_block(cfg, q_nope, q_rope, new_cache["ckv"],
-                            new_cache["krope"], params["wk_b"],
-                            params["wv_b"], mask, absorbed)
+    out = _mla_attend_block(cfg, q_nope, q_rope,
+                            _layer_of(new_cache["ckv"], layer, ckv.dtype),
+                            _layer_of(new_cache["krope"], layer, krope.dtype),
+                            params["wk_b"], params["wv_b"], mask, absorbed)
     return out.reshape(b, s, -1) @ hint(params["wo"], "heads_out", "wt_d"), new_cache
 
 

@@ -5,7 +5,9 @@ One *layer* = (pre-norm -> mixer block -> residual) + optional
 ``spec.post_norms``; the two halves run under ``jax.named_scope`` names,
 the mixer's kind (``attn``, ``mamba2``, ...) and ``mlp`` or ``moe``.  A
 *group* scans a repeating pattern of layers with stacked parameters; weight-shared slots (zamba2's shared attention) are
-closed over instead of scanned.  ``cfg.unroll`` switches the scan to a
+closed over instead of scanned.  With a cache, each slot's caches stay
+stacked over the repeats in the scan's carry, and each layer updates its
+index of the stack in place.  ``cfg.unroll`` switches the scan to a
 Python loop — used by the dry-run cost-accounting variants (DESIGN.md).
 """
 from __future__ import annotations
@@ -42,6 +44,12 @@ _CACHE_INIT = {
     "slstm": xlstm.init_slstm_cache,
 }
 
+#: mixers whose cache has a time axis: they write only their new rows into
+#: the stack of the group's caches, in place (``attention._write_rows``).
+#: The others rewrite their whole state each step and return it, and
+#: :func:`apply_layer` writes it into the stack.
+_ROWS_IN_PLACE = frozenset({"attn", "mla"})
+
 ZERO_AUX = {"moe_aux_loss": jnp.zeros((), jnp.float32),
             "moe_dropped": jnp.zeros((), jnp.float32)}
 
@@ -76,19 +84,28 @@ def init_layer_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
 
 
 def apply_layer(params: Params, cfg: ModelConfig, spec: LayerSpec,
-                x: jax.Array, ctx: dict, cache: Params | None
+                x: jax.Array, ctx: dict, cache: Params | None,
+                layer: jax.Array | int
                 ) -> tuple[jax.Array, Params | None, dict]:
+    """One layer.  ``cache`` is the stack of its slot's caches over the
+    group's repeats, of which this layer is index ``layer``; the stack is
+    returned updated there."""
     aux = dict(ZERO_AUX)
+    stack = cache
+    if cache is not None and spec.kind not in _ROWS_IN_PLACE:
+        cache = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, False), stack)
     if spec.kind != "none":
         with jax.named_scope(spec.kind):
             h = rmsnorm(params["pre_norm"], x, eps=cfg.norm_eps)
             if spec.kind == "attn":
                 h, new_cache = attention.apply_attn(
-                    params["mixer"], cfg, spec, h, ctx["positions"], cache)
+                    params["mixer"], cfg, spec, h, ctx["positions"], cache,
+                    layer)
             elif spec.kind == "mla":
                 h, new_cache = attention.apply_mla(
                     params["mixer"], cfg, spec, h, ctx["positions"], cache,
-                    absorbed=ctx.get("mla_absorbed", False))
+                    layer, absorbed=ctx.get("mla_absorbed", False))
             elif spec.kind == "cross_attn":
                 h, new_cache = attention.apply_cross_attn(
                     params["mixer"], cfg, spec, h, ctx.get("image_embeds"),
@@ -107,8 +124,12 @@ def apply_layer(params: Params, cfg: ModelConfig, spec: LayerSpec,
             if spec.post_norms:
                 h = rmsnorm(params["post_norm"], h, eps=cfg.norm_eps)
             x = x + h
+        if cache is not None and spec.kind not in _ROWS_IN_PLACE:
+            new_cache = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                    a, n.astype(a.dtype), layer, 0), stack, new_cache)
     else:
-        new_cache = cache
+        new_cache = stack
 
     if spec.mlp != "none":
         with jax.named_scope("moe" if spec.mlp == "moe" else "mlp"):
@@ -155,6 +176,10 @@ def init_group_cache(cfg: ModelConfig, gspec: GroupSpec, batch: int,
 def apply_group(params: Params, cfg: ModelConfig, gspec: GroupSpec,
                 x: jax.Array, ctx: dict, cache: Params | None
                 ) -> tuple[jax.Array, Params | None, dict]:
+    """The group's layers, repeat by repeat.  The caches are carried, not
+    scanned: each slot's stack ``(R, ...)`` passes through every repeat,
+    which updates its own index, so the stacks are updated in place and no
+    layer's cache is copied out of them or back."""
     pattern = gspec.pattern
     scanned_params = tuple(p for spec, p in zip(pattern, params["slots"])
                            if not spec.shared)
@@ -162,51 +187,32 @@ def apply_group(params: Params, cfg: ModelConfig, gspec: GroupSpec,
                           if spec.shared)
 
     def body(carry, per_repeat):
-        xc, aux_acc = carry
-        sl_params, sl_caches = per_repeat
+        xc, aux_acc, caches = carry
+        sl_params, r = per_repeat
         it_sc, it_sh = iter(sl_params), iter(shared_params)
         new_caches = []
         for i, spec in enumerate(pattern):
             p = next(it_sh) if spec.shared else next(it_sc)
-            c = sl_caches[i] if (sl_caches is not None and sl_caches[i]) \
-                else None
-            xc, nc, aux = apply_layer(p, cfg, spec, xc, ctx, c)
+            c = caches[i] if (caches is not None and caches[i]) else None
+            xc, nc, aux = apply_layer(p, cfg, spec, xc, ctx, c, r)
             new_caches.append({} if nc is None else nc)
             aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
-        return (xc, aux_acc), tuple(new_caches)
+        if caches is not None:
+            caches = tuple(new_caches)
+        return (xc, aux_acc, caches), None
 
     if cfg.remat != "none":
         policy = (jax.checkpoint_policies.checkpoint_dots
                   if cfg.remat == "dots" else None)
         body = jax.checkpoint(body, policy=policy, prevent_cse=cfg.unroll)
 
-    aux0 = dict(ZERO_AUX)
-    sl_caches = None if cache is None else cache["slots"]
+    carry = (x, dict(ZERO_AUX), None if cache is None else cache["slots"])
     if cfg.unroll:
-        carry = (x, aux0)
-        new_slots = [[] for _ in pattern]
         for r in range(gspec.repeat):
             sp = tuple(jax.tree.map(lambda a: a[r], p) for p in scanned_params)
-            sc = (None if sl_caches is None else
-                  tuple(jax.tree.map(lambda a: a[r], c) for c in sl_caches))
-            carry, ncs = body(carry, (sp, sc))
-            for i, nc in enumerate(ncs):
-                new_slots[i].append(nc)
-        (x, aux) = carry
-        if cache is None:
-            return x, None, aux
-        stacked = tuple(
-            jax.tree.map(lambda *a: jnp.stack(a), *ns) if ns and ns[0] else {}
-            for ns in new_slots)
-        return x, {"slots": stacked}, aux
-
-    xs = (scanned_params,
-          sl_caches if sl_caches is not None
-          else tuple(None for _ in pattern))
-    if sl_caches is None:
-        # scan requires uniform xs; use empty dicts as per-slot cache stand-in
-        xs = (scanned_params, tuple({} for _ in pattern))
-    (x, aux), new_caches = jax.lax.scan(body, (x, aux0), xs)
-    if cache is None:
-        return x, None, aux
-    return x, {"slots": new_caches}, aux
+            carry, _ = body(carry, (sp, r))
+    else:
+        carry, _ = jax.lax.scan(body, carry,
+                                (scanned_params, jnp.arange(gspec.repeat)))
+    x, aux, slots = carry
+    return x, None if cache is None else {"slots": slots}, aux

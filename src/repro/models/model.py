@@ -112,8 +112,10 @@ def _run(params: Params, cfg: ModelConfig, x: jax.Array, ctx: dict,
          caches: list | None):
     aux = dict(blocks.ZERO_AUX)
     new_caches = [] if caches is not None else None
-    # the layer loop's own work (slicing each layer's stacked weights and
-    # caches, writing the caches back) falls under ``layers`` alone
+    # the layer loop's own work (slicing each layer's stacked weights, and
+    # the slices and writes of the caches that a mixer rewrites whole)
+    # falls under ``layers`` alone; attention's rows are written in place
+    # under its own scope (blocks.apply_group)
     with jax.named_scope("layers"):
         for gi, gspec in enumerate(cfg.groups):
             c = None if caches is None else caches[gi]

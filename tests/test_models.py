@@ -135,3 +135,121 @@ def test_moe_router_bias_is_selection_only(rng):
             if isinstance(mlp, dict) and "router" in mlp \
                     and "bias" in mlp["router"]:
                 assert float(jnp.max(jnp.abs(mlp["router"]["bias"]))) == 0.0
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, those nested in others too."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _scans(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _scans(sub)
+
+
+@pytest.mark.parametrize("path", ["prefill", "decode_step"])
+@pytest.mark.parametrize("arch", ["deepseek_coder_33b", "zamba2_2_7b"])
+def test_cache_is_carried_not_scanned(arch, path, rng):
+    """No scan of the traced prefill or decode step takes an array with
+    the cache's time axis as a scanned input or gives one as a stacked
+    output, and every cache leaf is among the carries of the layer scan:
+    the stacked cache is updated in place, not sliced out and back."""
+    cfg = configs.get_config(arch, smoke=True)
+    b, max_len, s = 2, 23, 11
+    params = model_lib.abstract_params(cfg)
+    cache = model_lib.abstract_cache(cfg, b, max_len)
+    if path == "prefill":
+        toks, _ = _inputs(cfg, b, s, rng)
+        closed = jax.make_jaxpr(
+            lambda p, t, c: model_lib.prefill(p, cfg, t, c))(
+            params, toks, cache)
+    else:
+        toks, _ = _inputs(cfg, b, 1, rng)
+        closed = jax.make_jaxpr(
+            lambda p, t, c, q: model_lib.decode_step(p, cfg, t, c, q))(
+            params, toks, cache, jnp.full((b,), s, jnp.int32))
+    scans = list(_scans(closed.jaxpr))
+    assert scans
+    carried = set()
+    for eqn in scans:
+        n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = eqn.invars[n_const + n_carry:]
+        ys = eqn.outvars[n_carry:]
+        for var in (*xs, *ys):
+            assert max_len not in var.aval.shape, (var.aval, eqn)
+        carried |= {(v.aval.shape, v.aval.dtype)
+                    for v in eqn.invars[n_const:n_const + n_carry]}
+    leaves = jax.tree.leaves(cache)
+    assert any(max_len in a.shape for a in leaves)
+    for a in leaves:
+        assert (a.shape, a.dtype) in carried, a
+
+
+def _per_layer_run(params, cfg, tokens, cache, positions, img):
+    """The layer stack as a plain loop: each layer's params and cache are
+    sliced out of the stacks, run through ``blocks.apply_layer`` (the cache
+    as a stack of one) and the caches stacked again."""
+    from repro.models import blocks
+    from repro.models.common import rmsnorm
+    x = model_lib._embed(params, cfg, tokens)
+    ctx = {"positions": positions, "image_embeds": img}
+    new_groups = []
+    for gspec, gp, gc in zip(cfg.groups, params["groups"], cache):
+        slots = [[] for _ in gspec.pattern]
+        for r in range(gspec.repeat):
+            for i, spec in enumerate(gspec.pattern):
+                p = gp["slots"][i]
+                if not spec.shared:
+                    p = jax.tree.map(lambda a: a[r], p)
+                c = jax.tree.map(lambda a: a[r:r + 1], gc["slots"][i])
+                x, nc, _ = blocks.apply_layer(p, cfg, spec, x, ctx,
+                                              c or None, 0)
+                slots[i].append(jax.tree.map(lambda a: a[0], nc or {}))
+        new_groups.append({"slots": tuple(
+            jax.tree.map(lambda *a: jnp.stack(a), *s) if s[0] else {}
+            for s in slots)})
+    x = rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
+    return model_lib._head(params, cfg, x[:, -1:]), new_groups
+
+
+@pytest.mark.parametrize("arch", ["deepseek_coder_33b", "gemma2_27b",
+                                  "deepseek_v3_671b", "zamba2_2_7b",
+                                  "xlstm_350m", "llama3_2_vision_90b"])
+def test_carried_cache_matches_per_layer_loop(arch, rng):
+    """prefill then two decode steps, two sequences at different
+    positions, give the logits and the cache leaves of a per-layer loop
+    that slices each layer's cache out and stacks the results."""
+    cfg = configs.get_config(arch, smoke=True)
+    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
+    b, s, max_len = 2, 12, 24
+    toks, img = _inputs(cfg, b, s + 2, rng)
+    cache = model_lib.init_cache(cfg, b, max_len)
+    ref_cache = cache
+
+    got, cache = jax.jit(lambda p, t, c: model_lib.prefill(
+        p, cfg, t, c, img))(params, toks[:, :s], cache)
+    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    want, ref_cache = jax.jit(lambda p, t, c: _per_layer_run(
+        p, cfg, t, c, pos, img))(params, toks[:, :s], ref_cache)
+    steps = [(got, want)]
+    decode = jax.jit(lambda p, t, c, q: model_lib.decode_step(p, cfg, t, c, q))
+    ref_decode = jax.jit(lambda p, t, c, q: _per_layer_run(
+        p, cfg, t, c, q[:, None], None))
+    for j in range(2):
+        q = jnp.asarray([s + j, s - 5 + j], jnp.int32)
+        t = toks[:, s + j:s + j + 1]
+        got, cache = decode(params, t, cache, q)
+        want, ref_cache = ref_decode(params, t, ref_cache, q)
+        steps.append((got, want))
+    for got, want in steps:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+    assert (jax.tree.structure(cache) == jax.tree.structure(ref_cache))
+    for a, w in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):
+        assert a.shape == w.shape and a.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
