@@ -8,15 +8,14 @@ import time
 
 
 def main() -> None:
-    from benchmarks import (bench_roofline, bench_scaling, bench_scheduler,
-                            bench_server, bench_table1, bench_zero_worker)
+    from benchmarks import (bench_scaling, bench_scheduler, bench_server,
+                            bench_table1, bench_zero_worker)
     suites = [
         ("table1", bench_table1.run),
         ("scheduler(fig2)", bench_scheduler.run),
         ("server(fig3-4)", bench_server.run),
         ("scaling(fig5)", bench_scaling.run),
         ("zero_worker(fig6-8)", bench_zero_worker.run),
-        ("roofline", bench_roofline.run),
     ]
     print("name,us_per_call,derived")
     for name, fn in suites:

@@ -3,8 +3,7 @@ rope 64, nope 128, v 128), first 3 layers dense (d_ff 18432), 58 MoE layers
 with 1 shared + 256 routed experts (expert dim 2048), sigmoid top-8 router
 with aux-loss-free bias.  V=129280.  [arXiv:2412.19437]
 
-MTP is modelled as an optional single-depth extra head (see train_step);
-the assigned-shape dry-runs lower the main path.
+The multi-token-prediction (MTP) head is not modelled.
 """
 from repro.models.config import (GroupSpec, LayerSpec, MLAConfig,
                                  ModelConfig, MoEConfig)
@@ -27,7 +26,6 @@ def config() -> ModelConfig:
                       router_bias=True),
         activation="silu", tie_embeddings=False,
         rope_theta=10000.0, remat="full", fsdp=True,
-        optimizer="adafactor",
     )
 
 

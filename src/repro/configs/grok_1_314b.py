@@ -17,7 +17,6 @@ def config() -> ModelConfig:
         attn_softcap=30.0, final_softcap=30.0,
         activation="gelu", tie_embeddings=False,
         rope_theta=10000.0, remat="full", fsdp=True,
-        optimizer="adafactor",
     )
 
 

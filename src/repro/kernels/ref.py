@@ -55,8 +55,7 @@ def _attend_dense(q, k, v, *, causal, window, softcap, scale,
 
 # Above this query length, attention runs as an unrolled loop over query
 # blocks with the K/V range sliced to the causal/window support of each
-# block.  Bounds transient score memory to O(B*H*QB*T_blk) while keeping
-# all FLOPs visible to cost_analysis (no while loop) — DESIGN.md.
+# block.  Bounds transient score memory to O(B*H*QB*T_blk).
 BLOCK_THRESHOLD = 8192
 Q_BLOCK = 1024
 

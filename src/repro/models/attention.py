@@ -39,16 +39,12 @@ def init_attn(key, cfg: ModelConfig, spec: LayerSpec) -> Params:
     dt = cfg.compute_dtype
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     k1, k2, k3, k4 = take_keys(key, 4)
-    if cfg.fuse_qkv:
-        p = {"wqkv": dense_init(k1, d, ((h + 2 * kv) * hd,), dt),
-             "wo": dense_init(k4, h * hd, (d,), dt)}
-    else:
-        p = {
-            "wq": dense_init(k1, d, (h * hd,), dt),
-            "wk": dense_init(k2, d, (kv * hd,), dt),
-            "wv": dense_init(k3, d, (kv * hd,), dt),
-            "wo": dense_init(k4, h * hd, (d,), dt),
-        }
+    p = {
+        "wq": dense_init(k1, d, (h * hd,), dt),
+        "wk": dense_init(k2, d, (kv * hd,), dt),
+        "wv": dense_init(k3, d, (kv * hd,), dt),
+        "wo": dense_init(k4, h * hd, (d,), dt),
+    }
     if spec.qk_norm:
         p["q_norm"] = rmsnorm_init(hd, dt)
         p["k_norm"] = rmsnorm_init(hd, dt)
@@ -83,21 +79,12 @@ def apply_attn(params: Params, cfg: ModelConfig, spec: LayerSpec,
     """
     b, s, _ = x.shape
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    if cfg.fuse_qkv:
-        # one projection matmul + one FSDP gather instead of three
-        wqkv = hint(params["wqkv"], "wt_d", "heads_out")
-        qkv = jnp.einsum("bsd,dn->bsn", x, wqkv)
-        q, k, v = jnp.split(qkv, [h * hd, (h + kv) * hd], axis=-1)
-        q = q.reshape(b, s, h, hd)
-        k = k.reshape(b, s, kv, hd)
-        v = v.reshape(b, s, kv, hd)
-    else:
-        wq = hint(params["wq"], "wt_d", "heads_out")
-        wk = hint(params["wk"], "wt_d", "kv_out")
-        wv = hint(params["wv"], "wt_d", "kv_out")
-        q = jnp.einsum("bsd,dn->bsn", x, wq).reshape(b, s, h, hd)
-        k = jnp.einsum("bsd,dn->bsn", x, wk).reshape(b, s, kv, hd)
-        v = jnp.einsum("bsd,dn->bsn", x, wv).reshape(b, s, kv, hd)
+    wq = hint(params["wq"], "wt_d", "heads_out")
+    wk = hint(params["wk"], "wt_d", "kv_out")
+    wv = hint(params["wv"], "wt_d", "kv_out")
+    q = jnp.einsum("bsd,dn->bsn", x, wq).reshape(b, s, h, hd)
+    k = jnp.einsum("bsd,dn->bsn", x, wk).reshape(b, s, kv, hd)
+    v = jnp.einsum("bsd,dn->bsn", x, wv).reshape(b, s, kv, hd)
     q = hint(q, "batch", "attn_seq", "heads", None)
     k = hint(k, "batch", "seq", "kv_heads", None)
     v = hint(v, "batch", "seq", "kv_heads", None)

@@ -7,8 +7,7 @@ the mixer's kind (``attn``, ``mamba2``, ...) and ``mlp`` or ``moe``.  A
 *group* scans a repeating pattern of layers with stacked parameters; weight-shared slots (zamba2's shared attention) are
 closed over instead of scanned.  With a cache, each slot's caches stay
 stacked over the repeats in the scan's carry, and each layer updates its
-index of the stack in place.  ``cfg.unroll`` switches the scan to a
-Python loop — used by the dry-run cost-accounting variants (DESIGN.md).
+index of the stack in place.
 """
 from __future__ import annotations
 
@@ -204,15 +203,10 @@ def apply_group(params: Params, cfg: ModelConfig, gspec: GroupSpec,
     if cfg.remat != "none":
         policy = (jax.checkpoint_policies.checkpoint_dots
                   if cfg.remat == "dots" else None)
-        body = jax.checkpoint(body, policy=policy, prevent_cse=cfg.unroll)
+        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
 
     carry = (x, dict(ZERO_AUX), None if cache is None else cache["slots"])
-    if cfg.unroll:
-        for r in range(gspec.repeat):
-            sp = tuple(jax.tree.map(lambda a: a[r], p) for p in scanned_params)
-            carry, _ = body(carry, (sp, r))
-    else:
-        carry, _ = jax.lax.scan(body, carry,
-                                (scanned_params, jnp.arange(gspec.repeat)))
+    carry, _ = jax.lax.scan(body, carry,
+                            (scanned_params, jnp.arange(gspec.repeat)))
     x, aux, slots = carry
     return x, None if cache is None else {"slots": slots}, aux

@@ -105,22 +105,13 @@ class ModelConfig:
     # numerics / training
     activation: str = "silu"
     gated_mlp: bool = True            # GLU (False -> plain 2-matrix MLP)
-    unroll: bool = False              # Python-loop layers (dry-run costing)
-    # beyond-paper perf knobs (EXPERIMENTS.md §Perf)
-    fuse_qkv: bool = False            # single QKV projection matmul
-    fuse_glu: bool = False            # single gate+up projection matmul
-    seq_parallel: bool = False        # shard residual-stream seq over TP
-    loss_dtype: str = "float32"       # logsumexp accumulation dtype
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     remat: str = "full"               # 'none' | 'full' | 'dots'
     # distribution policy (consumed by repro.parallel.sharding)
     fsdp: bool = False                # shard big weight dims over 'data' too
-    moe_sharding: str = "auto"        # 'auto' | 'ep2d' | 'ep_fsdp' | 'tp'
     # sub-quadratic? (controls long_500k applicability)
     subquadratic: bool = False
-    # optimizer choice for train_step lowering
-    optimizer: str = "adamw"          # 'adamw' | 'adafactor' | 'lion'
 
     @property
     def num_layers(self) -> int:

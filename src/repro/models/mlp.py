@@ -20,10 +20,6 @@ def init_mlp(key, cfg: ModelConfig, d_ff: int | None = None,
     f = d_ff or cfg.d_ff
     gated = cfg.gated_mlp if gated is None else gated
     k1, k2, k3 = take_keys(key, 3)
-    if gated and cfg.fuse_glu:
-        # (D, 2, F) layout: F stays contiguous per shard after the split
-        return {"wgu": dense_init(k1, d, (2, f), dt),
-                "wo": dense_init(k3, f, (d,), dt)}
     p = {
         "wi": dense_init(k1, d, (f,), dt),   # gate (or sole up) proj
         "wo": dense_init(k3, f, (d,), dt),   # down proj
@@ -38,15 +34,10 @@ def apply_mlp(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     # weight hints = just-in-time FSDP gather (strip any 'data' shard) +
     # keep the TP dim explicit so GSPMD never replicates the F dim
     wo = hint(params["wo"], "ffn", "wt_d")
-    if "wgu" in params:  # fused gate+up: one matmul, one gather
-        wgu = hint(params["wgu"], "wt_d", None, "ffn")
-        gu = jnp.einsum("bsd,dgf->bsgf", x, wgu)
-        h = act(gu[:, :, 0]) * gu[:, :, 1]
-    else:
-        wi = hint(params["wi"], "wt_d", "ffn")
-        h = act(jnp.einsum("bsd,df->bsf", x, wi))
-        if "wu" in params:
-            h = h * jnp.einsum("bsd,df->bsf", x,
-                               hint(params["wu"], "wt_d", "ffn"))
+    wi = hint(params["wi"], "wt_d", "ffn")
+    h = act(jnp.einsum("bsd,df->bsf", x, wi))
+    if "wu" in params:
+        h = h * jnp.einsum("bsd,df->bsf", x,
+                           hint(params["wu"], "wt_d", "ffn"))
     h = hint(h, "batch", "seq", "ffn")
     return jnp.einsum("bsf,fd->bsd", h, wo)

@@ -158,8 +158,7 @@ def forward_loss(params: Params, cfg: ModelConfig, tokens: jax.Array,
     x, _, aux = _run(params, cfg, x, ctx, None)
 
     logits = _head(params, cfg, x)  # (B,S,V) or (B,S,K,V)
-    lse = jax.nn.logsumexp(logits.astype(jnp.dtype(cfg.loss_dtype)),
-                           axis=-1).astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
 
     if cfg.num_codebooks:
         w = params.get("head")

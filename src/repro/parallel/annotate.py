@@ -72,10 +72,7 @@ def make_rules(cfg, mesh: Mesh, batch: int) -> dict[str, Any]:
     b_axes = batch_axes(mesh, batch)
     rules: dict[str, Any] = {
         "batch": b_axes if b_axes else None,
-        # sequence parallelism (Megatron-SP style, validated in §Perf):
-        # shard the residual stream's seq dim over TP so norm/residual
-        # traffic divides by TP; all-reduces become all-gather/scatter
-        "seq": "model" if cfg.seq_parallel else None,
+        "seq": None,
         "heads": "model" if cfg.num_heads % tp == 0 else None,
         "kv_heads": "model" if cfg.num_kv_heads % tp == 0 else None,
         # sequence-parallel attention fallback: when the head count does
@@ -95,19 +92,12 @@ def make_rules(cfg, mesh: Mesh, batch: int) -> dict[str, Any]:
     if cfg.moe is not None:
         e = cfg.moe.num_experts
         dp = mesh.shape.get("data", 1)
-        mode = cfg.moe_sharding
-        if mode == "auto":
-            if e % (tp * dp) == 0 and tp * dp > 1:
-                mode = "ep2d"
-            elif e % tp == 0 and tp > 1:
-                mode = "ep_fsdp" if cfg.fsdp else "ep"
-            else:
-                mode = "tp"
-        if mode == "ep2d" and e % (tp * dp) == 0 and tp * dp > 1:
+        if e % (tp * dp) == 0 and tp * dp > 1:
+            # EP over model and data jointly
             rules["experts"] = ("model", "data")
             rules["expert_ffn"] = None
             rules["moe_groups"] = None
-        elif mode in ("ep", "ep_fsdp") and e % tp == 0 and tp > 1:
+        elif e % tp == 0 and tp > 1:
             # EP over model; expert weights FSDP-gathered over data at use
             rules["experts"] = "model"
             rules["expert_ffn"] = None

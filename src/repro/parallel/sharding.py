@@ -1,6 +1,6 @@
 """Sharding rules: parameter / optimizer / cache / input PartitionSpecs.
 
-Policy (baseline, hillclimbed in EXPERIMENTS.md §Perf):
+Policy:
   * TP over the ``model`` axis: attention heads, FFN hidden dim, vocab.
   * FSDP over ``data`` for archs flagged ``cfg.fsdp`` (big dense weights get
     their non-TP dim sharded over data; XLA inserts all-gathers).
@@ -11,11 +11,10 @@ Policy (baseline, hillclimbed in EXPERIMENTS.md §Perf):
     dim (flash-decoding-style sharded-KV softmax), batch over data axes.
 
 All rules are *hints*: GSPMD preserves correctness regardless; these choices
-drive the collective schedule measured in the roofline.
+drive the collective schedule.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -100,19 +99,9 @@ def _spec_entries(cfg: ModelConfig, mesh: Mesh, keys, name, shape) -> tuple:
     # --- MoE experts ---
     if "experts" in keys:
         e = cfg.moe.num_experts
-        mode = cfg.moe_sharding
-        if mode == "auto":
-            if e % (tp * dp) == 0 and tp * dp > 1:
-                mode = "ep2d"
-            elif e % tp == 0 and tp > 1:
-                mode = "ep_fsdp" if cfg.fsdp else "ep"
-            else:
-                mode = "tp"
-        if mode == "ep2d" and e % (tp * dp) == 0:     # EP over model+data
+        if e % (tp * dp) == 0 and tp * dp > 1:        # EP over model+data
             return (("model", "data"), None, None)
-        if mode == "ep_fsdp" and e % tp == 0:         # EP(model)+FSDP(data)
-            return ("model", "data" if ok(1, "data") else None, None)
-        if mode == "ep" and e % tp == 0 and tp > 1:   # EP over model
+        if e % tp == 0 and tp > 1:                    # EP over model
             return ("model", fsdp if ok(1, fsdp) else None, None)
         # TP inside experts: shard the F dim (dim2 for wi/wu, dim1 for wo)
         if name in ("wi", "wu"):
@@ -128,14 +117,6 @@ def _spec_entries(cfg: ModelConfig, mesh: Mesh, keys, name, shape) -> tuple:
                 "dt_bias", "d_skip", "r", "w_gates", "up", "down",
                 "up_gate", "w_in"):
         return tuple([None] * len(shape))
-
-    # --- fused projections (beyond-paper perf knobs) ---
-    if name == "wqkv":
-        return (fsdp if ok(0, fsdp) else None,
-                "model" if ok(1, "model") else None)
-    if name == "wgu":  # (D, 2, F)
-        return (fsdp if ok(0, fsdp) else None, None,
-                "model" if ok(2, "model") else None)
 
     # --- attention / MLP 2D weights ---
     if name in ("wq", "wq_b", "wk_b", "wv_b"):
@@ -162,15 +143,6 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> Any:
     shapes = model_lib.abstract_params(cfg)
     return jax.tree_util.tree_map_with_path(
         lambda p, l: NamedSharding(mesh, param_spec(cfg, mesh, p, l)), shapes)
-
-
-def abstract_sharded_params(cfg: ModelConfig, mesh: Mesh) -> Any:
-    shapes = model_lib.abstract_params(cfg)
-    return jax.tree_util.tree_map_with_path(
-        lambda p, l: jax.ShapeDtypeStruct(
-            l.shape, l.dtype,
-            sharding=NamedSharding(mesh, param_spec(cfg, mesh, p, l))),
-        shapes)
 
 
 # ---------------------------------------------------------------------------
@@ -210,16 +182,6 @@ def cache_spec(cfg: ModelConfig, mesh: Mesh, batch: int, path, leaf) -> P:
     if name == "filled":
         return P(*([None] * len(shape)))
     return P(None, b, *([None] * (len(shape) - 2)))
-
-
-def cache_shardings(cfg: ModelConfig, mesh: Mesh, batch: int,
-                    max_len: int) -> Any:
-    shapes = model_lib.abstract_cache(cfg, batch, max_len)
-    return jax.tree_util.tree_map_with_path(
-        lambda p, l: jax.ShapeDtypeStruct(
-            l.shape, l.dtype,
-            sharding=NamedSharding(mesh, cache_spec(cfg, mesh, batch, p, l))),
-        shapes)
 
 
 # ---------------------------------------------------------------------------

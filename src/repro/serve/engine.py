@@ -5,8 +5,7 @@ Requests enter a queue; the engine admits them into free KV-cache slots
 (prompt prefill, padded to bucket sizes to bound recompilation), then runs
 one batched decode step per iteration for all active slots.  Slots free as
 requests finish, new requests are admitted immediately — vLLM-style
-continuous batching on top of this framework's cache layout (which is the
-same layout the multi-pod dry-run shards).
+continuous batching on top of this framework's cache layout.
 
 The compute itself rides the persistent Cluster/Client futures API: the
 engine owns one warm single-executor :class:`repro.core.client.Cluster`

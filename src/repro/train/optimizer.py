@@ -2,9 +2,7 @@
 memory-frugal choice for the 300B+ archs), and Lion.
 
 Functional API: ``opt.init(params) -> state``; ``opt.apply(params, grads,
-state) -> (new_params, new_state, metrics)``.  ``opt.abstract_state``
-builds ShapeDtypeStructs with NamedShardings derived from the parameter
-specs so the dry-run can lower a full train_step without allocating.
+state) -> (new_params, new_state, metrics)``.
 """
 from __future__ import annotations
 
@@ -13,8 +11,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
-from jax.sharding import PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,43 +69,6 @@ class Optimizer:
 
     def apply(self, params, grads, state) -> tuple[Any, Any, dict]:
         raise NotImplementedError
-
-    def abstract_state(self, abstract_params, mesh=None) -> Any:
-        state = jax.eval_shape(self.init, abstract_params)
-        if mesh is None:
-            return state
-        return _attach_state_shardings(state, abstract_params, mesh)
-
-
-def _attach_state_shardings(state, abstract_params, mesh):
-    """Mirror param shardings onto state trees; reduced-rank leaves
-    (adafactor row/col stats) drop the matching trailing spec entries."""
-    flat_p = {tuple(str(k) for k in path): leaf
-              for path, leaf in
-              jax.tree_util.tree_flatten_with_path(abstract_params)[0]}
-
-    def fix(path, leaf):
-        keys = tuple(str(k) for k in path)
-        spec: tuple = ()
-        ref = None
-        for start in range(len(keys)):
-            ref = flat_p.get(keys[start:]) or flat_p.get(keys[start:-1])
-            if ref is not None:
-                break
-        if ref is not None and getattr(ref, "sharding", None) is not None:
-            pspec = tuple(ref.sharding.spec)
-            pspec = pspec + (None,) * (len(ref.shape) - len(pspec))
-            if leaf.shape == ref.shape:
-                spec = pspec
-            elif leaf.shape == ref.shape[:-1]:
-                spec = pspec[:-1]                      # row stats
-            elif len(ref.shape) >= 2 \
-                    and leaf.shape == ref.shape[:-2] + ref.shape[-1:]:
-                spec = pspec[:-2] + pspec[-1:]         # col stats
-        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                    sharding=NamedSharding(mesh, P(*spec)))
-
-    return jax.tree_util.tree_map_with_path(fix, state)
 
 
 class AdamW(Optimizer):

@@ -53,7 +53,7 @@ class Trainer:
                  optimizer: Optimizer | None = None):
         self.cfg = cfg
         self.tc = tc
-        self.opt = optimizer or make_optimizer(cfg.optimizer)
+        self.opt = optimizer or make_optimizer("adamw")
         key = jax.random.PRNGKey(tc.seed)
         self.params = model_lib.init_params(key, cfg)
         self.opt_state = self.opt.init(self.params)
@@ -173,7 +173,7 @@ class MicrobatchCoordinator:
         self.slow = slow_workers or {}
         self.memory_limit = memory_limit
         self._events = events
-        self.opt = optimizer or make_optimizer(cfg.optimizer)
+        self.opt = optimizer or make_optimizer("adamw")
         key = jax.random.PRNGKey(seed)
         self.params = model_lib.init_params(key, cfg)
         self.opt_state = self.opt.init(self.params)

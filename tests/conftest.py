@@ -1,7 +1,6 @@
 import os
 
-# Tests run on the single real CPU device; only launch/dryrun.py forces the
-# 512 placeholder devices (per the dry-run contract in the system design).
+# Tests run on the single real CPU device.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import pytest  # noqa: E402

@@ -137,6 +137,30 @@ def test_moe_router_bias_is_selection_only(rng):
                 assert float(jnp.max(jnp.abs(mlp["router"]["bias"]))) == 0.0
 
 
+def test_mla_absorbed_matches_baseline(rng):
+    """The weight-absorbed MLA decode path (beyond-paper opt) must equal
+    the naive K/V-expanding formulation."""
+    cfg = configs.get_config("deepseek_v3_671b", smoke=True)
+    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 16)), jnp.int32)
+    cache_a = model_lib.init_cache(cfg, 2, 32)
+    cache_b = model_lib.init_cache(cfg, 2, 32)
+    la, ca = model_lib.prefill(params, cfg, toks, cache_a,
+                               mla_absorbed=False)
+    lb, cb = model_lib.prefill(params, cfg, toks, cache_b,
+                               mla_absorbed=True)
+    np.testing.assert_allclose(np.asarray(lb), np.asarray(la),
+                               rtol=2e-4, atol=2e-4)
+    pos = jnp.full((2,), 16, jnp.int32)
+    nxt = toks[:, :1]
+    da, _ = model_lib.decode_step(params, cfg, nxt, ca, pos,
+                                  mla_absorbed=False)
+    db, _ = model_lib.decode_step(params, cfg, nxt, cb, pos,
+                                  mla_absorbed=True)
+    np.testing.assert_allclose(np.asarray(db), np.asarray(da),
+                               rtol=2e-4, atol=2e-4)
+
+
 def _scans(jaxpr):
     """Every ``scan`` equation of a jaxpr, those nested in others too."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
@@ -152,7 +176,9 @@ def _scans(jaxpr):
 
 
 @pytest.mark.parametrize("path", ["prefill", "decode_step"])
-@pytest.mark.parametrize("arch", ["deepseek_coder_33b", "zamba2_2_7b"])
+@pytest.mark.parametrize("arch", ["deepseek_coder_33b", "zamba2_2_7b",
+                                  "gemma2_27b", "deepseek_v3_671b",
+                                  "llama3_2_vision_90b"])
 def test_cache_is_carried_not_scanned(arch, path, rng):
     """No scan of the traced prefill or decode step takes an array with
     the cache's time axis as a scanned input or gives one as a stacked
@@ -163,9 +189,9 @@ def test_cache_is_carried_not_scanned(arch, path, rng):
     params = model_lib.abstract_params(cfg)
     cache = model_lib.abstract_cache(cfg, b, max_len)
     if path == "prefill":
-        toks, _ = _inputs(cfg, b, s, rng)
+        toks, img = _inputs(cfg, b, s, rng)
         closed = jax.make_jaxpr(
-            lambda p, t, c: model_lib.prefill(p, cfg, t, c))(
+            lambda p, t, c: model_lib.prefill(p, cfg, t, c, img))(
             params, toks, cache)
     else:
         toks, _ = _inputs(cfg, b, 1, rng)

@@ -129,7 +129,7 @@ def test_microbatch_grads_match_full_batch():
 
     # reference: single full-batch step from the same init
     loss_fn = make_loss_fn(cfg)
-    opt = make_optimizer(cfg.optimizer)
+    opt = make_optimizer("adamw")
     st = opt.init(p0)
     g = jax.grad(lambda p: loss_fn(p, {k: jnp.asarray(v)
                                        for k, v in batch.items()})[0])(p0)
@@ -203,20 +203,29 @@ def _reference_generate(cfg, params, prompt, n_new):
     return out
 
 
-def test_serving_engine_matches_reference(rng):
+# beyond the dense baseline: the engine's exact-length recurrent admission
+# (zamba2, xlstm), a sliding window shorter than the longer prompt
+# (gemma2), MLA rows and MoE routing through batched decode (deepseek-v3,
+# grok-1)
+@pytest.mark.parametrize("arch", [
+    "llama3.2-1b", "deepseek_coder_33b", "gemma2_27b", "zamba2_2_7b",
+    "xlstm_350m", "deepseek_v3_671b", "grok_1_314b"])
+def test_serving_engine_matches_reference(arch, rng):
     from repro.serve.engine import ServingEngine
-    cfg = CFG
+    cfg = configs.get_config(arch, smoke=True)
+    lengths, n_new = (((5, 9, 17), 6) if arch == "llama3.2-1b"
+                      else ((5, 17), 4))
     params = model_lib.init_params(jax.random.PRNGKey(1), cfg)
     eng = ServingEngine(cfg, params, max_batch=4, max_len=256)
     eng.start()
     prompts = [np.asarray(rng.integers(0, cfg.vocab_size, size=n))
-               for n in (5, 9, 17)]
-    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+               for n in lengths]
+    reqs = [eng.submit(p, max_new_tokens=n_new) for p in prompts]
     for r in reqs:
         assert r.done.wait(120)
     eng.stop()
     for p, r in zip(prompts, reqs):
-        want = _reference_generate(cfg, params, p, 6)
+        want = _reference_generate(cfg, params, p, n_new)
         assert r.out_tokens == want, (r.out_tokens, want)
 
 
