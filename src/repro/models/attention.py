@@ -82,9 +82,13 @@ def apply_attn(params: Params, cfg: ModelConfig, spec: LayerSpec,
     wq = hint(params["wq"], "wt_d", "heads_out")
     wk = hint(params["wk"], "wt_d", "kv_out")
     wv = hint(params["wv"], "wt_d", "kv_out")
-    q = jnp.einsum("bsd,dn->bsn", x, wq).reshape(b, s, h, hd)
-    k = jnp.einsum("bsd,dn->bsn", x, wk).reshape(b, s, kv, hd)
-    v = jnp.einsum("bsd,dn->bsn", x, wv).reshape(b, s, kv, hd)
+    # the fence keeps attention's head-major layout out of the dots, so they
+    # read the weights in the layout they are stored in, not a transposed copy
+    q, k, v = jax.lax.optimization_barrier(
+        tuple(jnp.einsum("bsd,dn->bsn", x, w) for w in (wq, wk, wv)))
+    q = q.reshape(b, s, h, hd)
+    k = k.reshape(b, s, kv, hd)
+    v = v.reshape(b, s, kv, hd)
     q = hint(q, "batch", "attn_seq", "heads", None)
     k = hint(k, "batch", "seq", "kv_heads", None)
     v = hint(v, "batch", "seq", "kv_heads", None)

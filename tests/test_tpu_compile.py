@@ -14,6 +14,7 @@ this one file.
 import dataclasses
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,7 @@ from repro.train.trainer import mean_and_apply
 HBM_BYTES = 16 * 2**30          # one v5e chip
 LLAMA = configs.get_config("llama3.2-1b")
 ZAMBA = configs.get_config("zamba2-2.7b")
+DEEPSEEK = configs.get_config("deepseek-coder-33b")
 
 
 @pytest.fixture(scope="module")
@@ -70,6 +72,22 @@ def _on_chip(tree, sharding):
 
 def _compile(fn, *args, **jit_kw):
     return jax.jit(fn, **jit_kw).lower(*args).compile()
+
+
+def _decode_fn(cfg):
+    """The serving engine's decode step: next tokens and the new cache."""
+    def decode_fn(params, tokens, cache, pos):
+        logits, cache = model_lib.decode_step(params, cfg, tokens, cache,
+                                              pos)
+        return jnp.argmax(logits[:, 0], axis=-1), cache
+    return decode_fn
+
+
+def _cut(cfg, layers):
+    """``cfg`` with its one group of layers cut to ``layers``."""
+    g, = cfg.groups
+    return dataclasses.replace(cfg, groups=(dataclasses.replace(
+        g, repeat=layers),))
 
 
 @pytest.mark.parametrize("s", [16, 128, 256])
@@ -128,14 +146,8 @@ def test_llama_decode_step_fits_one_chip(one_chip, impl):
     max_len 256: compiles, and its arguments, outputs and temporaries fit
     one chip's HBM."""
     cfg = LLAMA
-
-    def decode_fn(params, tokens, cache, pos):
-        logits, cache = model_lib.decode_step(params, cfg, tokens, cache,
-                                              pos)
-        return jnp.argmax(logits[:, 0], axis=-1), cache
-
     b, max_len = 8, 256
-    c = _compile(decode_fn,
+    c = _compile(_decode_fn(cfg),
                  _on_chip(model_lib.abstract_params(cfg), one_chip),
                  _spec(one_chip, (b, 1), jnp.int32),
                  _on_chip(model_lib.abstract_cache(cfg, b, max_len),
@@ -167,9 +179,7 @@ def test_microbatch_update_fits_one_chip(one_chip):
     """MicrobatchCoordinator's averaged-gradient optimizer update for the
     2-layer full-width cut that chip_smoke.py trains, with four
     microbatch gradients held: fits one chip with room to spare."""
-    g = LLAMA.groups[0]
-    cfg = dataclasses.replace(LLAMA, groups=(dataclasses.replace(
-        g, repeat=2),))
+    cfg = _cut(LLAMA, 2)
     opt = make_optimizer("adamw")
     params = model_lib.abstract_params(cfg)
     state = jax.eval_shape(opt.init, params)
@@ -182,3 +192,54 @@ def test_microbatch_update_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES // 2
+
+
+def test_llama_train_step_fits_one_chip(one_chip):
+    """The gradient of the training loss for the same 2-layer cut, batch 8
+    of 256 tokens: compiles, and fits one chip."""
+    cfg = _cut(LLAMA, 2)
+    grad = jax.grad(lambda p, t, l: model_lib.forward_loss(p, cfg, t, l)[0])
+    c = _compile(grad, _on_chip(model_lib.abstract_params(cfg), one_chip),
+                 _spec(one_chip, (8, 256), jnp.int32),
+                 _spec(one_chip, (8, 256), jnp.int32))
+    mem = c.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+def _copied_slices(hlo: str, shapes) -> list[str]:
+    """The copies and dynamic-slice fusions in ``hlo`` that produce a value
+    of one of ``shapes``."""
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = (.*?) (?:copy|copy-start"
+                     r"|fusion)\(", line)
+        if (m and re.search("copy|dynamic-slice", m[1])
+                and any(shape in m[2] for shape in shapes)):
+            found.append(m[1])
+    return found
+
+
+@pytest.mark.parametrize("mode", ["decode", "prefill"])
+def test_qkv_weights_read_in_place(one_chip, mode):
+    """deepseek-coder-33b's serving programs, cut to 2 layers: no layer's
+    slice of the stacked q/k/v projections is materialised or copied to
+    another layout; the projections read the weights where they lie."""
+    cfg = _cut(DEEPSEEK, 2)
+    d, h, kv, hd = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+                    cfg.head_dim)
+    params = _on_chip(model_lib.abstract_params(cfg), one_chip)
+    if mode == "decode":
+        b, max_len = 16, 1536
+        c = _compile(_decode_fn(cfg), params,
+                     _spec(one_chip, (b, 1), jnp.int32),
+                     _on_chip(model_lib.abstract_cache(cfg, b, max_len),
+                              one_chip),
+                     _spec(one_chip, (b,), jnp.int32), donate_argnums=(2,))
+    else:
+        c = _compile(lambda p, t, cache: model_lib.prefill(p, cfg, t, cache),
+                     params, _spec(one_chip, (1, 1024), jnp.int32),
+                     _on_chip(model_lib.abstract_cache(cfg, 1, 1536),
+                              one_chip))
+    shapes = [f"[1,{d},{h * hd}]", f"[1,{d},{kv * hd}]"]
+    assert _copied_slices(c.as_text(), shapes) == []
